@@ -187,7 +187,10 @@ def run(dataset: str = "email-enron", scale: float = SCALE, k: int = 8,
     rel_err = abs(tot["device_s"] - dev) / max(dev, 1e-9)
     shares = ledger.tenant_shares(None)      # lifetime, not windowed
     snap = ledger.snapshot()
-    utils = {t: a["utilization"] for t, a in snap["tenants"].items()}
+    # None where the device has no published peaks (no roofline bound)
+    utils = {t: a["utilization"] if a["utilization"] is None
+             else round(a["utilization"], 4)
+             for t, a in snap["tenants"].items()}
     srv_cost.close()
 
     os.makedirs(OUT_DIR, exist_ok=True)
@@ -249,8 +252,8 @@ def run(dataset: str = "email-enron", scale: float = SCALE, k: int = 8,
             "cached": tot["cached"],
             "share_heavy": round(shares.get("heavy", 0.0), 4),
             "share_cheap": round(shares.get("cheap", 0.0), 4),
-            "utilization_heavy": round(utils.get("heavy", 0.0), 4),
-            "utilization_cheap": round(utils.get("cheap", 0.0), 4),
+            "utilization_heavy": utils.get("heavy"),
+            "utilization_cheap": utils.get("cheap"),
         },
         "qps_ledger_off": round(qps_off, 2),
         "qps_ledger_on": round(qps_on, 2),

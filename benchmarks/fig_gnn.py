@@ -15,8 +15,8 @@ Two sweeps:
          ``speedup_f128`` gate against it (floor 1.5 in tolerances.json).
        * ``batched_ref`` — rank-3 ``gspmm_ref`` (gather, materialise the
          weighted message stream, scatter segment-sum in one XLA program).
-         Diagnostic only: the Pallas kernel runs in interpret mode on CPU
-         CI, and interpret-mode wall-clock is not device performance
+         Diagnostic only: on the CPU backend the Pallas kernel is
+         interpreted, and interpret-mode wall-clock is not device performance
          (kernel_bench.py states the same caveat) — on CPU, XLA's native
          scatter wins; the fused kernel exists for the lane-tiled TPU
          lowering.

@@ -8,10 +8,13 @@ partitions sharded over a forced 8-device host mesh
 latency for the mesh path next to the single-device path on the identical
 graph/plan/queries.
 
-The device-count flag must be set before jax is imported, so ``main``
-re-executes this module in a subprocess with the flag in the environment
-(the same pattern as tests/test_engine_distributed.py); the inner run
-emits ``BENCH_serve_mesh.json`` through the shared OUT_DIR machinery.
+On the CPU the device-count flag must be set before jax is imported, so
+``main`` re-executes this module in a subprocess with the flag in the
+environment (the same pattern as tests/test_engine_distributed.py).  On a
+TPU host the process that already holds the chips runs the figure itself
+over ``jax.devices()``: a child could not reach a chip its parent holds.
+Either way the run emits ``BENCH_serve_mesh.json`` through the shared
+OUT_DIR machinery.
 
 On a host CPU the 8 "devices" are one physical core time-sliced, so
 mesh qps is *not* expected to beat single-device here — the record holds
@@ -60,22 +63,22 @@ def _serve_point(eng, g, reqs, bucket: int, mode: str) -> dict:
             "pad_waste_frac": st["pad_waste_frac"]}
 
 
-def _inner() -> None:
-    import jax
+def _inner(devices) -> None:
     import numpy as np
+    from jax.sharding import Mesh
 
     from repro.core import dfep, graph
     from repro import engine as E
 
     from .common import SCALE, emit_json
 
-    n_dev = len(jax.devices())
-    assert n_dev == 8, f"expected the forced 8-device host mesh, got {n_dev}"
+    n_dev = len(devices)
     k, n_queries, bucket = 8, 32, 16
+    assert k % n_dev == 0, f"k={k} partitions cannot split over {n_dev} devices"
     g = graph.load_dataset("email-enron", scale=SCALE, seed=0)
     owner, _ = dfep.partition(g, k=k, key=0)
     plan = E.compile_plan(g, np.asarray(owner), k)
-    mesh = jax.make_mesh((8,), ("parts",))
+    mesh = Mesh(np.array(devices), ("parts",))
     # identical query streams (same seed), fresh request ids per server
     reqs_a = _queries(np.random.default_rng(0), g.n_vertices, n_queries)
     reqs_b = _queries(np.random.default_rng(0), g.n_vertices, n_queries)
@@ -83,13 +86,15 @@ def _inner() -> None:
     rows = [
         _serve_point(E.Engine(plan), g, reqs_a, bucket, "single-device"),
         _serve_point(E.Engine(plan, mesh=mesh), g, reqs_b, bucket,
-                     "mesh-8dev"),
+                     f"mesh-{n_dev}dev"),
     ]
     # the two paths must agree on everything but wall-clock
     assert rows[0]["batches"] == rows[1]["batches"]
     mesh_row = rows[1]
     emit_json("BENCH_serve_mesh", {
         "dataset": "email-enron", "scale": SCALE, "k": k,
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
         "n_vertices": g.n_vertices, "n_edges": g.n_edges,
         "n_devices": n_dev, "n_queries": n_queries, "bucket": bucket,
         "rows": rows,
@@ -99,8 +104,15 @@ def _inner() -> None:
 
 
 def main() -> None:
+    import jax
     if os.environ.get(_INNER_ENV) == "1":
-        _inner()
+        n_dev = len(jax.devices())
+        assert n_dev == 8, \
+            f"expected the forced 8-device host mesh, got {n_dev}"
+        _inner(jax.devices())
+        return
+    if jax.default_backend() == "tpu":
+        _inner(jax.devices())
         return
     env = dict(os.environ)
     env[_INNER_ENV] = "1"

@@ -43,6 +43,10 @@ def main() -> None:
         os.environ.setdefault("REPRO_BENCH_SCALE", "0.05")
         os.environ.setdefault("REPRO_BENCH_SAMPLES", "2")
 
+    # before the first compile: JAX's persistent cache at its one place
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
+
     # imports AFTER env so common.py picks the scales up
     from repro import obs
     from . import (fig5_k_sweep, fig6_diameter, fig7_comparison,

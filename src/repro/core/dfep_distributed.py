@@ -30,8 +30,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
+from ..sharding.env import auto_axes
 from .dfep import FREE, DfepConfig, _hash01, finalize
 from .graph import Graph
 
@@ -98,6 +98,7 @@ def run_dfep_sharded(g: Graph, cfg: DfepConfig, key: jax.Array,
                      mesh: Mesh, axis: str = "data"
                      ) -> tuple[jax.Array, dict]:
     """Run DFEP edge-sharded over ``mesh[axis]``. Returns (owner [E_pad], info)."""
+    mesh = auto_axes(mesh)
     ndev = mesh.shape[axis]
     sg = shard_graph(g, ndev)
     v_n, k = g.n_vertices, cfg.k
@@ -238,12 +239,12 @@ def run_dfep_sharded(g: Graph, cfg: DfepConfig, key: jax.Array,
         return owner[None], mv, rounds, stalled
 
     spec_e = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         worker, mesh=mesh,
         in_specs=(spec_e, spec_e, spec_e, spec_e, spec_e, spec_e, spec_e,
                   spec_e, P(), P(), P()),
         out_specs=(spec_e, P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     owner, mv, rounds, stalled = jax.jit(fn)(
         sg.src, sg.dst, sg.edge_mask, sg.slot_edge, sg.slot_vertex,

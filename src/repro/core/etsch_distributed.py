@@ -12,8 +12,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
+from ..sharding.env import auto_axes
 from .etsch import Partitioning
 
 
@@ -85,10 +85,10 @@ def sssp_sharded(part: Partitioning, source: int, mesh: Mesh,
         out = jax.lax.pmin(jnp.min(jnp.where(member, dist, inf), axis=0), axis)
         return out, steps
 
-    fn = shard_map(worker, mesh=mesh,
-                   in_specs=(P(axis), P(axis), P(axis), P(axis)),
-                   out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(worker, mesh=auto_axes(mesh),
+                       in_specs=(P(axis), P(axis), P(axis), P(axis)),
+                       out_specs=(P(), P()),
+                       check_vma=False)
     dist, steps = jax.jit(fn)(part.src, part.dst, part.mask, part.member)
     return dist, int(steps)
 
@@ -120,7 +120,7 @@ def pagerank_sharded(part: Partitioning, degrees: jax.Array, mesh: Mesh,
         rank, _ = jax.lax.scan(step, rank, None, length=iters)
         return rank
 
-    fn = shard_map(worker, mesh=mesh,
-                   in_specs=(P(axis), P(axis), P(axis)),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(worker, mesh=auto_axes(mesh),
+                       in_specs=(P(axis), P(axis), P(axis)),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn)(part.src, part.dst, part.mask)

@@ -10,8 +10,9 @@ Three kernels, all specialized to the ``PartitionPlan`` CSR blocks:
     is one partition's independent edge stream), the edge-slot axis is
     blocked into [BLK_S, K] VMEM tiles walked sequentially, and a [1, K]
     VMEM scratch carries the running value of each lane's open segment
-    across tiles.  Inside a tile the segmented combine runs as an
-    associative scan on (segment-start flag, value) pairs.  The caller then
+    across tiles.  Inside a tile the segmented combine runs as a log-step
+    Hillis–Steele scan on (segment-start flag, value) pairs over the
+    sublane axis (``pltpu.roll`` row shifts + masks).  The caller then
     picks each vertex's aggregate out of the scanned stream at
     ``plan.last_slot`` (a plain gather; padding slots hold the identity
     because the padding region starts a fresh identity-valued segment).
@@ -34,7 +35,9 @@ Three kernels, all specialized to the ``PartitionPlan`` CSR blocks:
     [K, V]-tile style of kernels/frontier_min.py.
 
 All support combine ∈ {"min", "add", "max"} (SSSP/WCC, PageRank, GNN
-max-pooling) and run in interpret mode on CPU.  ``segment_reduce``,
+max-pooling).  No caller picks how a kernel runs: ``_pallas_on_platform``
+compiles it with Mosaic when the computation is lowered for a TPU and
+interprets it on the CPU backend.  ``segment_reduce``,
 ``segment_reduce_ref`` and ``masked_update`` accept either scalar
 [K, ·] streams or [K, ·, F] feature planes — the F axis is folded onto
 the 128-wide lane axis, so scalar programs are literally the F=1 case
@@ -65,6 +68,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 _IDENTITY = {"min": jnp.inf, "add": 0.0, "max": -jnp.inf}
 _OPS = {"min": jnp.minimum, "add": jnp.add, "max": jnp.maximum}
+_TILE_BYTES = 512 * 1024    # one f32 edge-stream tile in VMEM
+_BLOCK_V = 2048             # vertex slots per masked_update tile
 
 
 def _scatter_combine(tgt: jax.Array, rows: jax.Array, cols: jax.Array,
@@ -79,34 +84,85 @@ def _scatter_combine(tgt: jax.Array, rows: jax.Array, cols: jax.Array,
     return at.add(vals)
 
 
-def _seg_kernel(flags_ref, vals_ref, o_ref, carry_ref, *, combine: str):
-    op = _OPS[combine]
-    ident = jnp.float32(_IDENTITY[combine])
-    i = pl.program_id(0)
+def _pallas_on_platform(call, *args):
+    """Run ``call(*args, interpret=...)`` for the platform the computation
+    is lowered for: the CPU backend interprets the kernel body (how the
+    tests run), ``tpu`` compiles it with Mosaic.  The choice is staged with
+    ``lax.platform_dependent``, so an ahead-of-time compile for a TPU from a
+    CPU-only process still gets the compiled kernel."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise NotImplementedError(
+            f"the engine's Pallas kernels run compiled on TPU or "
+            f"interpreted on CPU; no kernel path exists for {backend!r}")
+    return jax.lax.platform_dependent(
+        *args, cpu=functools.partial(call, interpret=True),
+        tpu=functools.partial(call, interpret=False))
 
-    @pl.when(i == 0)
-    def _init():
-        carry_ref[...] = jnp.full_like(carry_ref, ident)
 
-    f = flags_ref[...]                        # [BLK_S, K] bool
-    v = vals_ref[...]                         # [BLK_S, K] f32
+def _block_rows(s: int, lanes: int) -> int:
+    """Edge-slot rows per VMEM tile: one f32 [rows, lanes] tile stays at
+    ``_TILE_BYTES`` so the double-buffered operands of the widest kernel
+    (gspmm: four inputs + one output) fit the default scoped VMEM at any
+    lane width; never more rows than the (8-aligned) stream holds."""
+    rows = max(8, _TILE_BYTES // (4 * lanes) // 8 * 8)
+    return min(rows, -(-s // 8) * 8)
 
-    def comb(a, b):
-        af, av = a
-        bf, bv = b
-        return af | bf, jnp.where(bf, bv, op(av, bv))
 
-    f_scan, v_scan = jax.lax.associative_scan(comb, (f, v), axis=0)
-    # rows before the tile's first segment start continue the carried segment
-    out = jnp.where(f_scan, v_scan, op(carry_ref[...], v_scan))
+def _seg_scan_tile(f, v, op):
+    """Inclusive segmented scan of one [rows, lanes] tile along the
+    sublane axis: a log-step Hillis–Steele scan on (start flag, value)
+    pairs.  Step ``d`` combines each row with the row ``d`` above it
+    (``pltpu.roll`` shifts rows down; rows above ``d`` have no partner),
+    unless the row already saw a segment start.  f: int32 0/1, v: f32."""
+    row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    d = 1
+    while d < v.shape[0]:
+        has = row >= d
+        pf = pltpu.roll(f, d, 0)
+        pv = pltpu.roll(v, d, 0)
+        v = jnp.where(has & (f == 0), op(pv, v), v)
+        f = jnp.where(has, f | pf, f)
+        d *= 2
+    return f, v
+
+
+def _carry_out(f, v, o_ref, carry_ref, op):
+    """Rows before the tile's first segment start continue the segment
+    carried in from the previous tile; the last row carries forward."""
+    out = jnp.where(f != 0, v, op(carry_ref[...], v))
     o_ref[...] = out
     carry_ref[...] = out[-1:, :]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("combine", "block_s", "interpret"))
-def segment_scan(flags: jax.Array, vals: jax.Array, combine: str = "min",
-                 block_s: int = 1024, interpret: bool = True) -> jax.Array:
+def _seg_kernel(flags_ref, vals_ref, o_ref, carry_ref, *, combine: str):
+    op = _OPS[combine]
+
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        carry_ref[...] = jnp.full_like(carry_ref, _IDENTITY[combine])
+
+    f, v = _seg_scan_tile(flags_ref[...], vals_ref[...], op)
+    _carry_out(f, v, o_ref, carry_ref, op)
+
+
+def _seg_call(flags, vals, *, combine: str, block_s: int, interpret: bool):
+    s, k = vals.shape
+    spec = pl.BlockSpec((block_s, k), lambda i: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_seg_kernel, combine=combine),
+        grid=(s // block_s,),
+        in_specs=[spec] * 2,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((s, k), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, k), jnp.float32)],
+        interpret=interpret,
+    )(flags, vals)
+
+
+@functools.partial(jax.jit, static_argnames=("combine",))
+def segment_scan(flags: jax.Array, vals: jax.Array,
+                 combine: str = "min") -> jax.Array:
     """Segmented inclusive scan along axis 0 of [S, K] streams.
 
     ``flags[s, k]`` True starts a new segment in lane k.  Returns the
@@ -115,26 +171,20 @@ def segment_scan(flags: jax.Array, vals: jax.Array, combine: str = "min",
     """
     s, k = vals.shape
     ident = _IDENTITY[combine]
-    s_pad = -(-s // block_s) * block_s
     k_pad = -(-k // 128) * 128
-    fp = jnp.zeros((s_pad, k_pad), jnp.bool_).at[:s, :k].set(flags)
+    block_s = _block_rows(s, k_pad)
+    s_pad = -(-s // block_s) * block_s
+    fp = jnp.zeros((s_pad, k_pad), jnp.int32).at[:s, :k].set(flags)
     # padding rows/lanes: identity values, no segment starts — harmless
     vp = jnp.full((s_pad, k_pad), ident, jnp.float32).at[:s, :k].set(vals)
-    out = pl.pallas_call(
-        functools.partial(_seg_kernel, combine=combine),
-        grid=(s_pad // block_s,),
-        in_specs=[pl.BlockSpec((block_s, k_pad), lambda i: (i, 0)),
-                  pl.BlockSpec((block_s, k_pad), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_s, k_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((s_pad, k_pad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((1, k_pad), jnp.float32)],
-        interpret=interpret,
-    )(fp, vp)
+    out = _pallas_on_platform(
+        functools.partial(_seg_call, combine=combine, block_s=block_s),
+        fp, vp)
     return out[:s, :k]
 
 
-def segment_reduce(plan, messages: jax.Array, combine: str = "min",
-                   block_s: int = 1024, interpret: bool = True) -> jax.Array:
+def segment_reduce(plan, messages: jax.Array,
+                   combine: str = "min") -> jax.Array:
     """Per-target aggregates over the plan's CSR stream.
 
     messages [K, Emax] or [K, Emax, F] (identity at masked slots) ->
@@ -158,8 +208,7 @@ def segment_reduce(plan, messages: jax.Array, combine: str = "min",
     msgs = jnp.where((plan.emask & in_csr)[:, :, None], msgs3, ident)
     stream = msgs.transpose(1, 0, 2).reshape(e_max, k * f)       # [Emax, K·F]
     flags = jnp.repeat(plan.seg_start.T, f, axis=1)
-    scanned = segment_scan(flags, stream, combine=combine,
-                           block_s=block_s, interpret=interpret)
+    scanned = segment_scan(flags, stream, combine=combine)
     scanned = scanned.reshape(e_max, k, f).transpose(1, 0, 2)    # [K, Emax, F]
     rows = jnp.arange(k, dtype=jnp.int32)[:, None]
     agg = scanned[rows, plan.last_slot]                          # [K, Vmax, F]
@@ -231,71 +280,64 @@ def segment_reduce_ref(plan, messages: jax.Array,
 
 
 def _gspmm_kernel(flags_ref, mask_ref, w_ref, vals_ref, o_ref, carry_ref, *,
-                  combine: str, features: int):
+                  combine: str):
     """Fused multiply + segmented combine over one [BLK_S, K·F] tile.
 
-    ``flags``/``mask``/scalar ``w`` arrive K-wide and are broadcast to the
-    K·F lane layout in VMEM (features minor); per-feature weight planes
-    arrive K·F-wide already.  The weighted message x = v·w is formed and
+    Every operand arrives in the K·F lane layout (partitions major,
+    features minor).  The weighted message x = v·w is formed and
     identity-masked inside the kernel — the weighted stream never exists
     in HBM.
     """
     op = _OPS[combine]
     ident = jnp.float32(_IDENTITY[combine])
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         carry_ref[...] = jnp.full_like(carry_ref, ident)
 
-    fl = jnp.repeat(flags_ref[...], features, axis=1)     # [BLK_S, K·F]
-    ok = jnp.repeat(mask_ref[...], features, axis=1)
-    w = w_ref[...]
-    if w.shape[1] != fl.shape[1]:       # scalar per-half-edge weights
-        w = jnp.repeat(w, features, axis=1)
     # multiply BEFORE masking: a dead slot's weight can never rescue it,
     # and the identity (±inf for min/max) is never multiplied by 0
-    x = jnp.where(ok, vals_ref[...] * w, ident)
-
-    def comb(a, b):
-        af, av = a
-        bf, bv = b
-        return af | bf, jnp.where(bf, bv, op(av, bv))
-
-    f_scan, v_scan = jax.lax.associative_scan(comb, (fl, x), axis=0)
-    out = jnp.where(f_scan, v_scan, op(carry_ref[...], v_scan))
-    o_ref[...] = out
-    carry_ref[...] = out[-1:, :]
+    x = jnp.where(mask_ref[...] != 0, vals_ref[...] * w_ref[...], ident)
+    f, v = _seg_scan_tile(flags_ref[...], x, op)
+    _carry_out(f, v, o_ref, carry_ref, op)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("combine", "block_s", "interpret"))
-def _gspmm_scan(flags: jax.Array, mask: jax.Array, w: jax.Array,
-                vals: jax.Array, combine: str, block_s: int,
-                interpret: bool) -> jax.Array:
-    """Segmented scan of masked v·w streams: flags/mask [S, K] bool,
-    w [S, K] or [S, K·F], vals [S, K·F] -> scanned [S, K·F]."""
+def _gspmm_call(flags, mask, w, vals, *, combine: str, block_s: int,
+                interpret: bool):
     s, kf = vals.shape
-    k = flags.shape[1]
-    f = kf // k
-    ident = _IDENTITY[combine]
-    s_pad = -(-s // block_s) * block_s
-    fp = jnp.zeros((s_pad, k), jnp.bool_).at[:s].set(flags)
-    mp = jnp.zeros((s_pad, k), jnp.bool_).at[:s].set(mask)
-    wp = jnp.zeros((s_pad, w.shape[1]), jnp.float32).at[:s].set(w)
-    vp = jnp.zeros((s_pad, kf), jnp.float32).at[:s].set(vals)
-    out = pl.pallas_call(
-        functools.partial(_gspmm_kernel, combine=combine, features=f),
-        grid=(s_pad // block_s,),
-        in_specs=[pl.BlockSpec((block_s, k), lambda i: (i, 0)),
-                  pl.BlockSpec((block_s, k), lambda i: (i, 0)),
-                  pl.BlockSpec((block_s, w.shape[1]), lambda i: (i, 0)),
-                  pl.BlockSpec((block_s, kf), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_s, kf), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((s_pad, kf), jnp.float32),
+    spec = pl.BlockSpec((block_s, kf), lambda i: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_gspmm_kernel, combine=combine),
+        grid=(s // block_s,),
+        in_specs=[spec] * 4,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((s, kf), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, kf), jnp.float32)],
         interpret=interpret,
-    )(fp, mp, wp, vp)
+    )(flags, mask, w, vals)
+
+
+@functools.partial(jax.jit, static_argnames=("combine",))
+def _gspmm_scan(flags: jax.Array, mask: jax.Array, w: jax.Array,
+                vals: jax.Array, combine: str) -> jax.Array:
+    """Segmented scan of masked v·w streams: flags/mask [S, K] bool,
+    w [S, K] or [S, K·F], vals [S, K·F] -> scanned [S, K·F].  K-wide
+    operands are spread to the K·F lane layout here, outside the kernel."""
+    s, kf = vals.shape
+    f = kf // flags.shape[1]
+    block_s = _block_rows(s, kf)
+    s_pad = -(-s // block_s) * block_s
+
+    def lanes(x, dtype):
+        x = x.astype(dtype)
+        if x.shape[1] != kf:
+            x = jnp.repeat(x, f, axis=1)
+        return jnp.zeros((s_pad, kf), dtype).at[:s].set(x)
+
+    out = _pallas_on_platform(
+        functools.partial(_gspmm_call, combine=combine, block_s=block_s),
+        lanes(flags, jnp.int32), lanes(mask, jnp.int32),
+        lanes(w, jnp.float32), lanes(vals, jnp.float32))
     return out[:s]
 
 
@@ -308,8 +350,8 @@ def _pad_k(x: jax.Array, k_pad: int, fill) -> jax.Array:
     return jnp.concatenate([x, pad], axis=0)
 
 
-def gspmm(plan, feats: jax.Array, weights: jax.Array, combine: str = "add",
-          *, block_s: int = 1024, interpret: bool = True) -> jax.Array:
+def gspmm(plan, feats: jax.Array, weights: jax.Array,
+          combine: str = "add") -> jax.Array:
     """Fused gSpMM: gather · multiply · segment-reduce in one kernel pass.
 
     DGL's ``u_mul_e_{sum,max,mean}`` shape on the partition-local layout:
@@ -332,10 +374,9 @@ def gspmm(plan, feats: jax.Array, weights: jax.Array, combine: str = "add",
     if combine == "sum":
         combine = "add"
     if combine == "mean":
-        s = gspmm(plan, feats, weights, "add", block_s=block_s,
-                  interpret=interpret)
+        s = gspmm(plan, feats, weights, "add")
         cnt = segment_reduce(plan, jnp.ones(plan.emask.shape, jnp.float32),
-                             "add", block_s=block_s, interpret=interpret)
+                             "add")
         return s / jnp.maximum(cnt, 1.0)[:, :, None]
     if feats.ndim == 2:
         feats = feats[:, :, None]
@@ -360,8 +401,7 @@ def gspmm(plan, feats: jax.Array, weights: jax.Array, combine: str = "add",
     else:
         wop = _pad_k(w3, k_pad, 0.0).transpose(1, 0, 2).reshape(
             e_max, k_pad * f)
-    scanned = _gspmm_scan(flags, maskt, wop, vals, combine=combine,
-                          block_s=block_s, interpret=interpret)
+    scanned = _gspmm_scan(flags, maskt, wop, vals, combine=combine)
     scanned = scanned.reshape(e_max, k_pad, f).transpose(1, 0, 2)[:k]
     agg = scanned[rows, plan.last_slot]                     # [K, Vmax, F]
     # append-region half-edges: weighted outside the kernel (the region is
@@ -400,11 +440,23 @@ def _update_kernel(state_ref, inc_ref, vmask_ref, rep_ref, o_ref, *,
     o_ref[...] = jnp.where(vmask_ref[...], new, ident)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("combine", "block_v", "interpret"))
+def _update_call(state, incoming, vmask, replicated, *, combine: str,
+                 interpret: bool):
+    k_pad, v_pad = state.shape
+    spec = pl.BlockSpec((k_pad, _BLOCK_V), lambda i: (0, i))
+    return pl.pallas_call(
+        functools.partial(_update_kernel, combine=combine),
+        grid=(v_pad // _BLOCK_V,),
+        in_specs=[spec] * 4,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((k_pad, v_pad), jnp.float32),
+        interpret=interpret,
+    )(state, incoming, vmask, replicated)
+
+
+@functools.partial(jax.jit, static_argnames=("combine",))
 def masked_update(state: jax.Array, incoming: jax.Array, vmask: jax.Array,
-                  replicated: jax.Array, combine: str = "min",
-                  block_v: int = 2048, interpret: bool = True) -> jax.Array:
+                  replicated: jax.Array, combine: str = "min") -> jax.Array:
     """Apply exchanged values to replicated slots: state/incoming [K, Vmax]
     or [K, Vmax, F] (the feature axis folds onto the slot axis — masks are
     per-vertex, so they broadcast by repetition)."""
@@ -414,23 +466,16 @@ def masked_update(state: jax.Array, incoming: jax.Array, vmask: jax.Array,
                             incoming.reshape(k, v * f),
                             jnp.repeat(vmask, f, axis=1),
                             jnp.repeat(replicated, f, axis=1),
-                            combine=combine, block_v=block_v,
-                            interpret=interpret)
+                            combine=combine)
         return out.reshape(k, v, f)
     k, v = state.shape
     ident = _IDENTITY[combine]
     k_pad = -(-k // 8) * 8
-    v_pad = -(-v // block_v) * block_v
+    v_pad = -(-v // _BLOCK_V) * _BLOCK_V
     sp = jnp.full((k_pad, v_pad), ident, jnp.float32).at[:k, :v].set(state)
     ip = jnp.full((k_pad, v_pad), ident, jnp.float32).at[:k, :v].set(incoming)
     mp = jnp.zeros((k_pad, v_pad), jnp.bool_).at[:k, :v].set(vmask)
     rp = jnp.zeros((k_pad, v_pad), jnp.bool_).at[:k, :v].set(replicated)
-    out = pl.pallas_call(
-        functools.partial(_update_kernel, combine=combine),
-        grid=(v_pad // block_v,),
-        in_specs=[pl.BlockSpec((k_pad, block_v), lambda i: (0, i))] * 4,
-        out_specs=pl.BlockSpec((k_pad, block_v), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((k_pad, v_pad), jnp.float32),
-        interpret=interpret,
-    )(sp, ip, mp, rp)
+    out = _pallas_on_platform(
+        functools.partial(_update_call, combine=combine), sp, ip, mp, rp)
     return out[:k, :v]

@@ -342,7 +342,9 @@ def _gcn_apply(old, agg, ctx):
 
 def _gcn_finalize(glob, present, plan, ctx):
     h = jnp.where(present[:, None], glob, 0.0)
-    return jnp.dot(h, ctx["weight"])
+    # full f32 products: the TPU's default matmul precision rounds the
+    # operands to bf16, far outside the layer's 1e-5 oracle tolerance
+    return jnp.dot(h, ctx["weight"], precision=jax.lax.Precision.HIGHEST)
 
 
 GCN_LAYER = EdgeProgram(

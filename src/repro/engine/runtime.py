@@ -19,7 +19,8 @@ Steps 1–2 repeat until the exchanged state reaches a global fixed point
 Two device mappings, same numerics:
 
   * **single-device fallback** — the [K, ...] partition axis is a batch
-    axis; segment-reduce runs in the Pallas kernel (interpret mode on CPU);
+    axis; segment-reduce runs in the Pallas kernel (compiled on TPU,
+    interpreted on the CPU backend);
   * **shard_map** — partitions are sharded over a 1-d device mesh axis
     (``K % n_devices == 0``, each device holds a [K/D, ...] block); the
     exchange's cross-partition combine becomes a device-local scatter
@@ -45,7 +46,6 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import obs as _obs
@@ -201,14 +201,13 @@ def _expand(mask: jax.Array, ref: jax.Array) -> jax.Array:
     return mask[:, :, None] if ref.ndim == 3 else mask
 
 
-def _sweep(plan, prog, state, ctx, *, use_pallas: bool, interpret: bool):
+def _sweep(plan, prog, state, ctx, *, use_pallas: bool):
     """One Gather-Apply sweep: per-target aggregate [K, Vmax(, F)]."""
     pre = prog.pre(state, ctx)                              # [K, Vmax(, F)]
     if prog.edge_mul is not None:   # fused gSpMM path (GNN programs)
         w = prog.edge_mul(plan, ctx)
         if use_pallas:
-            agg = kernels.gspmm(plan, pre, w, prog.combine,
-                                interpret=interpret)
+            agg = kernels.gspmm(plan, pre, w, prog.combine)
         else:
             agg = kernels.gspmm_ref(plan, pre, w, prog.combine)
         return agg[:, :, 0] if pre.ndim == 2 else agg
@@ -216,13 +215,12 @@ def _sweep(plan, prog, state, ctx, *, use_pallas: bool, interpret: bool):
     if prog.edge is not None:   # per-half-edge hook (weighted programs)
         msgs = prog.edge(msgs, plan, ctx)
     if use_pallas:
-        return kernels.segment_reduce(plan, msgs, prog.combine,
-                                      interpret=interpret)
+        return kernels.segment_reduce(plan, msgs, prog.combine)
     return kernels.segment_reduce_ref(plan, msgs, prog.combine)
 
 
 def _exchange(plan, values, combine, axis: str | None, *,
-              use_pallas: bool, interpret: bool):
+              use_pallas: bool):
     """Combine replicated slots across partitions; private slots unchanged.
 
     values [K, Vmax(, F)] -> same shape. With ``axis`` set (shard_map body)
@@ -251,7 +249,7 @@ def _exchange(plan, values, combine, axis: str | None, *,
     inc = glob[plan.local2global]                           # [K, Vmax(, F)]
     if use_pallas:
         return kernels.masked_update(values, inc, plan.vmask, plan.replicated,
-                                     combine, interpret=interpret)
+                                     combine)
     new = jnp.where(_expand(plan.replicated, values), inc, values)
     return jnp.where(_expand(plan.vmask, values), new, ident)
 
@@ -274,7 +272,7 @@ def _gather_global(plan, state, axis: str | None):
 
 def _run_loop(plan: PartitionPlan, prog: EdgeProgram, kw: dict,
               prev: jax.Array | None, axis: str | None, max_supersteps: int,
-              max_local_iters: int, use_pallas: bool, interpret: bool):
+              max_local_iters: int, use_pallas: bool):
     """The superstep loop (runs as-is on one device or inside shard_map).
 
     ``prev`` (None or a [V] previous-epoch result) selects cold vs warm
@@ -293,13 +291,12 @@ def _run_loop(plan: PartitionPlan, prog: EdgeProgram, kw: dict,
         state0 = prog.init(plan, ctx)
     else:
         state0 = prog.warm_init(plan, prev, ctx)
-    opts = dict(use_pallas=use_pallas, interpret=interpret)
 
     if prog.mode == "replica":
         def local_phase(st):
             def body(c):
                 s, it, _ = c
-                agg = _sweep(plan, prog, s, ctx, **opts)
+                agg = _sweep(plan, prog, s, ctx, use_pallas=use_pallas)
                 ns = prog.apply(s, agg, ctx)
                 return ns, it + 1, jnp.any(ns != s)
 
@@ -314,7 +311,8 @@ def _run_loop(plan: PartitionPlan, prog: EdgeProgram, kw: dict,
         def superstep(carry):
             st, steps, litot, _ = carry
             st1, li = local_phase(st)
-            st2 = _exchange(plan, st1, prog.combine, axis, **opts)
+            st2 = _exchange(plan, st1, prog.combine, axis,
+                            use_pallas=use_pallas)
             changed = jnp.any(st2 != st)
             if axis is not None:
                 changed = jax.lax.pmax(changed.astype(jnp.int32), axis) > 0
@@ -326,8 +324,9 @@ def _run_loop(plan: PartitionPlan, prog: EdgeProgram, kw: dict,
         converged = ~changed    # still changing => the cap cut us off
     else:  # partial aggregation: lock-step, fixed superstep count
         def superstep(st, _):
-            agg = _sweep(plan, prog, st, ctx, **opts)
-            agg_full = _exchange(plan, agg, prog.combine, axis, **opts)
+            agg = _sweep(plan, prog, st, ctx, use_pallas=use_pallas)
+            agg_full = _exchange(plan, agg, prog.combine, axis,
+                                 use_pallas=use_pallas)
             return prog.apply(st, agg_full, ctx), None
 
         st, _ = jax.lax.scan(superstep, state0, None, length=max_supersteps)
@@ -342,19 +341,17 @@ def _run_loop(plan: PartitionPlan, prog: EdgeProgram, kw: dict,
 
 
 @partial(jax.jit, static_argnames=("prog", "max_supersteps",
-                                   "max_local_iters", "use_pallas",
-                                   "interpret"))
+                                   "max_local_iters", "use_pallas"))
 def _run_single(plan, prog, kw, prev, max_supersteps, max_local_iters,
-                use_pallas, interpret):
+                use_pallas):
     return _run_loop(plan, prog, kw, prev, None, max_supersteps,
-                     max_local_iters, use_pallas, interpret)
+                     max_local_iters, use_pallas)
 
 
 @partial(jax.jit, static_argnames=("prog", "mesh", "axis", "k_local",
-                                   "max_supersteps", "max_local_iters",
-                                   "interpret"))
+                                   "max_supersteps", "max_local_iters"))
 def _run_sharded(plan, kw, prev, *, prog, mesh, axis, k_local,
-                 max_supersteps, max_local_iters, interpret):
+                 max_supersteps, max_local_iters):
     """Module-level so repeated queries hit one jit cache entry per
     (program, mesh, shape) — the serving path never retraces."""
     plan_spec = jax.tree_util.tree_map(lambda _: P(axis), plan)
@@ -365,20 +362,18 @@ def _run_sharded(plan, kw, prev, *, prog, mesh, axis, k_local,
         plan_local = dataclasses.replace(plan_local, k=k_local)
         return _run_loop(plan_local, prog, kw_local, prev_local, axis,
                          max_supersteps, max_local_iters,
-                         use_pallas=False, interpret=interpret)
+                         use_pallas=False)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(plan_spec, kw_spec, prev_spec),
-                   out_specs=(P(), P(), P(), P()), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(plan_spec, kw_spec, prev_spec),
+                       out_specs=(P(), P(), P(), P()), check_vma=False)
     return fn(plan, kw, prev)
 
 
 @partial(jax.jit, static_argnames=("prog", "mesh", "axis", "k_local",
-                                   "max_supersteps", "max_local_iters",
-                                   "interpret"))
+                                   "max_supersteps", "max_local_iters"))
 def _run_sharded_batched(plan, kw, batched_kw, prev, *, prog, mesh, axis,
-                         k_local, max_supersteps, max_local_iters,
-                         interpret):
+                         k_local, max_supersteps, max_local_iters):
     """Batched queries on the shard_map path: partitions stay sharded over
     the mesh axis while the batch axis is vmapped *inside* the sharded body,
     so one superstep loop answers the whole micro-batch with the same
@@ -395,15 +390,15 @@ def _run_sharded_batched(plan, kw, batched_kw, prev, *, prog, mesh, axis,
         def one(bkw, pv):
             return _run_loop(plan_local, prog, {**kw_local, **bkw}, pv,
                              axis, max_supersteps, max_local_iters,
-                             use_pallas=False, interpret=interpret)
+                             use_pallas=False)
 
         if prev_local is None:
             return jax.vmap(lambda bkw: one(bkw, None))(bkw_local)
         return jax.vmap(one)(bkw_local, prev_local)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(plan_spec, kw_spec, bkw_spec, prev_spec),
-                   out_specs=(P(), P(), P(), P()), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(plan_spec, kw_spec, bkw_spec, prev_spec),
+                       out_specs=(P(), P(), P(), P()), check_vma=False)
     return fn(plan, kw, batched_kw, prev)
 
 
@@ -412,14 +407,14 @@ class Engine:
     """Partitioned execution engine bound to a plan (and optionally a mesh).
 
     ``mesh`` must be 1-d with axis name ``axis`` and a device count dividing
-    ``plan.k``; without a mesh the single-device fallback runs with the
-    Pallas kernels in interpret mode.
+    ``plan.k``; without a mesh the single-device path runs the Pallas
+    kernels, compiled for the platform the computation runs on (Mosaic on
+    TPU, the interpreter on the CPU backend).
     """
     plan: PartitionPlan
     mesh: Mesh | None = None
     axis: str = "parts"
     use_pallas: bool = True
-    interpret: bool = True
 
     def with_plan(self, plan: PartitionPlan) -> "Engine":
         """Rebind to a (patched or recompiled) plan. A patched plan shares
@@ -492,15 +487,13 @@ class Engine:
         with self._obs_dispatch(prog, 0):
             if self.mesh is None:
                 out = _run_single(self.plan, prog, kw, prev, steps,
-                                  max_local_iters, self.use_pallas,
-                                  self.interpret)
+                                  max_local_iters, self.use_pallas)
             else:
                 out = _run_sharded(self._sharded_plan(), kw, prev, prog=prog,
                                    mesh=self.mesh, axis=self.axis,
                                    k_local=self._k_local(),
                                    max_supersteps=steps,
-                                   max_local_iters=max_local_iters,
-                                   interpret=self.interpret)
+                                   max_local_iters=max_local_iters)
         return PendingResult(out, self.plan.exchange_volume)
 
     def run(self, prog: EdgeProgram, max_supersteps: int | None = None,
@@ -517,9 +510,9 @@ class Engine:
         batch axis of ``batched_kw`` (e.g. ``{"source": sources}`` for
         multi-source SSSP). Runs on one device or, with a mesh bound, with
         the batch axis vmapped inside the shard_map body. The XLA
-        segment-reduce path is used (vmapping the interpreted Pallas grid is
-        unsupported). The serving scheduler dispatches the next micro-batch
-        while this one computes and syncs via ``.result()``.
+        segment-reduce path is used (the Pallas kernels are not vmapped).
+        The serving scheduler dispatches the next micro-batch while this
+        one computes and syncs via ``.result()``.
         ``warm_state`` is a [S, V] block, one previous-result row per lane
         (+inf rows cold-start their lane)."""
         steps = _steps(prog, max_supersteps)
@@ -533,14 +526,14 @@ class Engine:
                     def one(bkw):
                         return _run_single(self.plan, prog, {**kw, **bkw},
                                            None, steps, max_local_iters,
-                                           False, self.interpret)
+                                           False)
 
                     out = jax.vmap(one)(batched_kw)
                 else:
                     def one_warm(bkw, pv):
                         return _run_single(self.plan, prog, {**kw, **bkw},
                                            pv, steps, max_local_iters,
-                                           False, self.interpret)
+                                           False)
 
                     out = jax.vmap(one_warm)(batched_kw, prev)
             else:
@@ -549,8 +542,7 @@ class Engine:
                                            mesh=self.mesh, axis=self.axis,
                                            k_local=self._k_local(),
                                            max_supersteps=steps,
-                                           max_local_iters=max_local_iters,
-                                           interpret=self.interpret)
+                                           max_local_iters=max_local_iters)
         return PendingResult(out, self.plan.exchange_volume)
 
     def run_batched(self, prog: EdgeProgram, batched_kw: dict,
@@ -581,14 +573,13 @@ class Engine:
             if self.mesh is None:
                 lowered = _run_single.lower(
                     self.plan, prog, kw, None, steps, max_local_iters,
-                    self.use_pallas, self.interpret)
+                    self.use_pallas)
             else:
                 lowered = _run_sharded.lower(
                     self._sharded_plan(), kw, None, prog=prog,
                     mesh=self.mesh, axis=self.axis,
                     k_local=self._k_local(), max_supersteps=steps,
-                    max_local_iters=max_local_iters,
-                    interpret=self.interpret)
+                    max_local_iters=max_local_iters)
         else:
             batched_kw = {k: jnp.asarray(v) for k, v in batched_kw.items()}
             if self.mesh is None:
@@ -598,7 +589,7 @@ class Engine:
                 def one(bkw):
                     return _run_single(self.plan, prog, {**kw, **bkw},
                                        None, steps, max_local_iters,
-                                       False, self.interpret)
+                                       False)
 
                 lowered = jax.jit(jax.vmap(one)).lower(batched_kw)
             else:
@@ -606,8 +597,7 @@ class Engine:
                     self._sharded_plan(), kw, batched_kw, None, prog=prog,
                     mesh=self.mesh, axis=self.axis,
                     k_local=self._k_local(), max_supersteps=steps,
-                    max_local_iters=max_local_iters,
-                    interpret=self.interpret)
+                    max_local_iters=max_local_iters)
         return lowered.compile().as_text()
 
     # -- shard_map plumbing -------------------------------------------------

@@ -262,7 +262,8 @@ class GraphServer:
                      version: int) -> _PlanBuffer:
         # serving runs the XLA segment-reduce path by default: batched
         # dispatch requires it, and unbatched programs (WCC/PageRank) then
-        # share one code path instead of the interpreted Pallas grid
+        # share one code path with it (which path wins on the chip is
+        # for the benchmark to decide)
         engine = dataclasses.replace(engine, use_pallas=self.use_pallas)
         return _PlanBuffer(engine, graph, int(epoch), int(version))
 
@@ -678,12 +679,14 @@ class GraphServer:
             if fl.pending is not None and disp:
                 model = fl.cost
                 n = len(disp)
+                util = None   # no roofline bound: "not available"
                 if model is not None and model.error is None:
                     b_fl, b_by, b_cb = model.cost(sweeps)
-                    util = (model.attainable_s(sweeps) / exec_dt
-                            if exec_dt > 0 else 0.0)
+                    bound = model.attainable_s(sweeps)
+                    if bound is not None and exec_dt > 0:
+                        util = bound / exec_dt
                 else:
-                    b_fl = b_by = b_cb = util = 0.0
+                    b_fl = b_by = b_cb = 0.0
                 for r in disp:
                     led.post(CostSample(
                         tenant=r.tenant, program=r.kind, graph=fp,

@@ -22,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs import SHAPES, all_archs, get_config
 from ..roofline import analysis as RA
-from ..sharding.env import get_env, use_mesh
+from ..sharding.env import auto_axes, get_env, use_mesh
 from ..serve import serve_step
 from ..train.optimizer import AdamWConfig, OptState
 from ..train.train_step import train_step
@@ -56,7 +56,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
              perf: bool = False) -> dict:
     from ..models.perf import BASELINE, TUNED, set_perf
     set_perf(TUNED if perf else BASELINE)
-    mesh = M.make_production_mesh(multi_pod=multi_pod)
+    mesh = auto_axes(M.make_production_mesh(multi_pod=multi_pod))
     n_chips = mesh.size
     rec = {"arch": arch, "shape": shape_name, "perf": perf,
            "mesh": "2x16x16" if multi_pod else "16x16", "chips": n_chips}

@@ -26,6 +26,7 @@ from ..data.pipeline import DataConfig, SyntheticPipeline
 from ..models import lm
 from ..models.perf import TUNED, set_perf
 from ..sharding.env import use_mesh
+from .mesh import make_mesh
 from ..train.optimizer import AdamWConfig, OptState, init_opt_state
 from ..train.train_step import train_step
 from ..ckpt.checkpoint import CheckpointManager
@@ -35,7 +36,7 @@ def parse_mesh(s: str):
     dims = tuple(int(x) for x in s.split("x"))
     axes = {1: ("data",), 2: ("data", "model"),
             3: ("pod", "data", "model")}[len(dims)]
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def main() -> None:

@@ -23,7 +23,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..configs.base import MlaConfig, ModelConfig, MoeConfig
 from ..sharding.env import get_env, shard
@@ -515,13 +514,13 @@ def moe(cfg: ModelConfig, p: dict, x: jax.Array) -> tuple[jax.Array, jax.Array]:
             e_lo = jax.lax.axis_index(env.tp) * e_loc
             return worker(xt_, router_, wg_, wu_, wd_, e_lo=e_lo)
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             wrapped, mesh=env.mesh,
             in_specs=(P(dp_spec, None), P(None, None),
                       P(env.tp, None, None), P(env.tp, None, None),
                       P(env.tp, None, None)),
             out_specs=(P(dp_spec, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(xt, p["router"], p["w_gate"], p["w_up"], p["w_down"])
         aux = jnp.mean(aux)
     else:

@@ -53,8 +53,9 @@ class CostSample:
     ``CostModel.cost(sweeps)`` split the same way.  ``utilization`` is
     achieved-vs-attainable: the roofline lower bound on the batch's
     device time divided by its measured time, in [0, 1] up to model
-    error.  Cache hits post ``from_cache=True`` with zero device time so
-    request accounting still balances.
+    error — or ``None`` where no bound exists (a device without published
+    peaks, a failed cost model).  Cache hits post ``from_cache=True``
+    with zero device time so request accounting still balances.
     """
 
     tenant: str
@@ -68,7 +69,7 @@ class CostSample:
     supersteps: int = 0
     n_requests: int = 1
     from_cache: bool = False
-    utilization: float = 0.0
+    utilization: float | None = None
 
 
 @dataclass
@@ -85,7 +86,8 @@ class _Series:
     requests: int = 0
     dispatched: int = 0
     cached: int = 0
-    util_s: float = 0.0          # sum(utilization * device_s)
+    util_s: float = 0.0          # sum(utilization * device_s) ...
+    util_device_s: float = 0.0   # ... over the samples that had one
 
     def post(self, s: CostSample) -> None:
         self.hist.record(s.device_s)
@@ -99,7 +101,9 @@ class _Series:
             self.cached += int(s.n_requests)
         else:
             self.dispatched += int(s.n_requests)
-        self.util_s += s.utilization * s.device_s
+        if s.utilization is not None:
+            self.util_s += s.utilization * s.device_s
+            self.util_device_s += s.device_s
 
     def merge(self, other: "_Series") -> None:
         self.hist.merge(other.hist)
@@ -112,6 +116,7 @@ class _Series:
         self.dispatched += other.dispatched
         self.cached += other.cached
         self.util_s += other.util_s
+        self.util_device_s += other.util_device_s
 
     def stats(self) -> dict:
         return {
@@ -119,8 +124,9 @@ class _Series:
             "hbm_bytes": self.hbm_bytes, "coll_bytes": self.coll_bytes,
             "supersteps": self.supersteps, "requests": self.requests,
             "dispatched": self.dispatched, "cached": self.cached,
-            "utilization": (self.util_s / self.device_s
-                            if self.device_s > 0 else 0.0),
+            "utilization": (self.util_s / self.util_device_s
+                            if self.util_device_s > 0 else None),
+            "utilization_device_s": self.util_device_s,
             "device_hist": self.hist.stats(),
         }
 
@@ -203,15 +209,19 @@ class CostLedger:
             agg = tenants.setdefault(row["tenant"], {
                 "device_s": 0.0, "flops": 0.0, "hbm_bytes": 0.0,
                 "coll_bytes": 0.0, "requests": 0, "dispatched": 0,
-                "cached": 0, "util_s": 0.0})
+                "cached": 0, "util_s": 0.0, "utilization_device_s": 0.0})
             for k in ("device_s", "flops", "hbm_bytes", "coll_bytes",
-                      "requests", "dispatched", "cached"):
+                      "requests", "dispatched", "cached",
+                      "utilization_device_s"):
                 agg[k] += row[k]
-            agg["util_s"] += row["utilization"] * row["device_s"]
+            if row["utilization"] is not None:
+                agg["util_s"] += (row["utilization"]
+                                  * row["utilization_device_s"])
         for t, agg in tenants.items():
             util_s = agg.pop("util_s")
-            agg["utilization"] = (util_s / agg["device_s"]
-                                  if agg["device_s"] > 0 else 0.0)
+            agg["utilization"] = (util_s / agg["utilization_device_s"]
+                                  if agg["utilization_device_s"] > 0
+                                  else None)
             agg["window_share"] = shares.get(t, 0.0)
         return {"kind": SNAPSHOT_KIND, "version": 1,
                 "window_s": self.window_s, "totals": self.totals(),

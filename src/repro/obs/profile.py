@@ -28,21 +28,25 @@ This module must not import ``repro.engine`` (the engine imports
 """
 from __future__ import annotations
 
-import os
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Any
 
+import jax
+
 from ..roofline.hlo_parse import analyze_hlo
 from . import recorder as _rec
 
-# Nominal device peaks for achieved-vs-attainable utilization.  These are
-# deliberately env-tunable *nominals*, not measured values: utilization is
-# a comparable ranking signal across tenants on the same host, not an
-# absolute hardware-efficiency claim.
-PEAK_FLOPS = float(os.environ.get("REPRO_PEAK_FLOPS", 5e10))
-PEAK_HBM_BPS = float(os.environ.get("REPRO_PEAK_BW", 2e10))
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``:
+# (peak FLOP/s, HBM bytes/s).  TPU v5e: 197 TFLOP/s bf16 and 819 GB/s HBM
+# (Google Cloud documentation, "TPU v5e").  A device kind that is not in
+# the table has no roofline: ``attainable_s`` — and the utilization the
+# serving ledger prices from it — is ``None`` ("not available"), never a
+# guessed number.
+DEVICE_PEAKS: dict[str, tuple[float, float]] = {
+    "TPU v5 lite": (197e12, 819e9),
+}
 
 _CACHE_CAP = 256
 
@@ -70,6 +74,7 @@ class CostModel:
     hlo_chars: int = 0
     compile_s: float = 0.0
     error: str | None = None
+    device_kind: str | None = None
 
     @property
     def arithmetic_intensity(self) -> float:
@@ -82,12 +87,16 @@ class CostModel:
         return (self.flops_per_sweep * s, self.hbm_bytes_per_sweep * s,
                 self.coll_bytes_per_sweep * s)
 
-    def attainable_s(self, sweeps: int) -> float:
+    def attainable_s(self, sweeps: int) -> float | None:
         """Roofline lower bound on device time for ``sweeps`` sweeps: the
         slower of the compute and memory ceilings (collective bytes ride
-        the HBM term — a deliberate single-node simplification)."""
+        the HBM term — a deliberate single-node simplification).  ``None``
+        on a device kind without published peaks."""
+        peaks = DEVICE_PEAKS.get(self.device_kind)
+        if peaks is None:
+            return None
         fl, by, _ = self.cost(sweeps)
-        return max(fl / PEAK_FLOPS, by / PEAK_HBM_BPS)
+        return max(fl / peaks[0], by / peaks[1])
 
 
 _LOCK = threading.Lock()
@@ -135,6 +144,9 @@ def cost_model(engine: Any, prog: Any, *, bucket: int | None = None,
     import time
     t0 = time.perf_counter()
     try:
+        mesh = engine.mesh
+        device = (mesh.devices.flat[0] if mesh is not None
+                  else jax.devices()[0])
         hlo = engine.lower_hlo(prog, batched_kw=batched_kw,
                                max_supersteps=max_supersteps, **kw)
         costs = analyze_hlo(hlo, trip_clamp=1)
@@ -144,7 +156,8 @@ def cost_model(engine: Any, prog: Any, *, bucket: int | None = None,
             hbm_bytes_per_sweep=costs.bytes_traffic,
             coll_bytes_per_sweep=costs.coll_bytes,
             unmodeled_ops=costs.unmodeled_ops, hlo_chars=len(hlo),
-            compile_s=time.perf_counter() - t0)
+            compile_s=time.perf_counter() - t0,
+            device_kind=device.device_kind)
     except Exception as e:  # noqa: BLE001 — profiling never breaks serving
         model = CostModel(
             program=key[0], plan_key=key[1], bucket=bucket,

@@ -62,6 +62,12 @@ def _eng(v: float) -> str:
     return f"{v:.2f}"
 
 
+def _util(v: float | None) -> str:
+    """Utilisation as a percentage; ``n/a`` where the device has no
+    published peaks to bound it."""
+    return "n/a" if v is None else f"{v:.1%}"
+
+
 def render(ledger: dict, top: int = 10) -> str:
     totals = ledger.get("totals", {})
     tenants = ledger.get("tenants", {})
@@ -93,7 +99,7 @@ def render(ledger: dict, top: int = 10) -> str:
                 f"{a.get('window_share', 0.0):>6.1%} "
                 f"{_eng(a.get('flops', 0.0)):>9} "
                 f"{_eng(a.get('hbm_bytes', 0.0)):>9} "
-                f"{a.get('utilization', 0.0):>6.1%}")
+                f"{_util(a.get('utilization')):>6}")
 
     ranked = sorted(series, key=lambda s: -s.get("device_s", 0.0))[:top]
     if ranked:
@@ -109,7 +115,7 @@ def render(ledger: dict, top: int = 10) -> str:
                 f"{s.get('epoch', 0):>3} {s.get('requests', 0):>5} "
                 f"{s.get('device_s', 0.0):>10.4f} "
                 f"{hist.get('p99', 0.0):>10.6f} "
-                f"{s.get('utilization', 0.0):>6.1%}")
+                f"{_util(s.get('utilization')):>6}")
     return "\n".join(lines)
 
 

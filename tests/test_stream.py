@@ -136,12 +136,15 @@ def test_patch_exhaustion_raises_and_leaves_plan_usable():
                                              vertex_slack=0,
                                              drift_threshold=1e9), key=0)
     plan = sess.plan
-    free = int(plan.e_max - 1 - int(np.asarray(plan.csr_fill).max()))
+    # every insert below targets partition 0, so size the overflow from
+    # partition 0's own free CSR slots (at least one insert, so a full
+    # partition still overflows)
+    free = int(plan.e_max - 1 - int(np.asarray(plan.csr_fill)[0]))
     # distinct in-range vertex pairs; 2 slots per edge overruns `free` slots
     import itertools
     pairs = itertools.combinations(range(g.n_vertices), 2)
     too_many = [EdgeChange(a, b, -1, 0)
-                for a, b in itertools.islice(pairs, free)]
+                for a, b in itertools.islice(pairs, max(free, 1))]
     with pytest.raises(SlackExhausted):
         patch_plan(plan, too_many)
     # the input plan is untouched and still answers queries
