@@ -127,7 +127,7 @@ class ImportLayering(Rule):
                 if after and after[0] in forbidden and \
                         after[0] != mod.subsystem:
                     yield self.finding(
-                        mod, node, "<module>",
+                        mod, node, qualname_at(mod.tree, node),
                         f"{mod.subsystem!r} must not import "
                         f"repro.{after[0]} (layering: "
                         f"{mod.subsystem} forbids {', '.join(forbidden)})")

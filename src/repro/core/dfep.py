@@ -147,120 +147,128 @@ def _round(g: Graph, slots: Slots, cfg: DfepConfig, state: DfepState,
     owner, mv = state.owner, state.mv
     part_ids = jnp.arange(k, dtype=jnp.int32)
 
-    free = owner == FREE                                             # [E]
-    owned_by = owner[:, None] == part_ids[None, :]                   # [E, K]
-
     # ---- step 1: spread units over eligible incident edges ---------------
-    elig = (free[:, None] | owned_by) & emask[:, None]               # [E, K]
-    if cfg.variant_c:
-        sizes0 = _sizes(owner, k)
-        mean0 = jnp.sum(sizes0) // k
-        poor = sizes0 < (mean0 / cfg.poor_p)                         # [K]
-        rich_edge = jnp.where(owner >= 0, ~poor[jnp.clip(owner, 0)], False)
-        raid = rich_edge[:, None] & poor[None, :] & ~owned_by & emask[:, None]
-        elig = elig | raid
+    with jax.named_scope("dfep.spread"):
+        free = owner == FREE                                         # [E]
+        owned_by = owner[:, None] == part_ids[None, :]               # [E, K]
+        elig = (free[:, None] | owned_by) & emask[:, None]           # [E, K]
+        if cfg.variant_c:
+            sizes0 = _sizes(owner, k)
+            mean0 = jnp.sum(sizes0) // k
+            poor = sizes0 < (mean0 / cfg.poor_p)                     # [K]
+            rich_edge = jnp.where(owner >= 0, ~poor[jnp.clip(owner, 0)], False)
+            raid = (rich_edge[:, None] & poor[None, :] & ~owned_by
+                    & emask[:, None])
+            elig = elig | raid
 
-    eligi = elig.astype(jnp.int32)
-    cnt = jnp.zeros((g.n_vertices, k), jnp.int32)
-    cnt = cnt.at[u].add(eligi).at[v].add(eligi)                      # [V, K]
-    safe_cnt = jnp.maximum(cnt, 1)
-    base = mv // safe_cnt                                            # [V, K]
-    rem = mv - base * safe_cnt                                       # [V, K]
+        eligi = elig.astype(jnp.int32)
+        cnt = jnp.zeros((g.n_vertices, k), jnp.int32)
+        cnt = cnt.at[u].add(eligi).at[v].add(eligi)                  # [V, K]
+        safe_cnt = jnp.maximum(cnt, 1)
+        base = mv // safe_cnt                                        # [V, K]
+        rem = mv - base * safe_cnt                                   # [V, K]
 
-    # per-slot rank among this vertex's eligible edges (segmented cumsum),
-    # rotated by a per-(vertex, partition, round) hash so the remainder units
-    # don't starve late-ranked edges (Hadoop's arbitrary iteration order)
-    elig_slot = eligi[slots.edge]                                    # [2E, K]
-    cum = jnp.cumsum(elig_slot, axis=0)
-    exc = cum - elig_slot                                            # exclusive
-    rank = exc - exc[slots.seg_first]                                # [2E, K]
-    sv = slots.vertex
-    rot = (_hash01(sv[:, None], part_ids[None, :], state.rounds)
-           * safe_cnt[sv].astype(jnp.float32)).astype(jnp.int32)
-    rank = jnp.where(safe_cnt[sv] > 0,
-                     (rank + rot) % safe_cnt[sv], rank)
-    contrib = elig_slot * (base[sv] + (rank < rem[sv]).astype(jnp.int32))
-    moved = cnt > 0
-    mv_left = jnp.where(moved, 0, mv)                                # [V, K]
+        # per-slot rank among this vertex's eligible edges (segmented
+        # cumsum), rotated by a per-(vertex, partition, round) hash so the
+        # remainder units don't starve late-ranked edges (Hadoop's arbitrary
+        # iteration order)
+        elig_slot = eligi[slots.edge]                                # [2E, K]
+        cum = jnp.cumsum(elig_slot, axis=0)
+        exc = cum - elig_slot                                        # exclusive
+        rank = exc - exc[slots.seg_first]                            # [2E, K]
+        sv = slots.vertex
+        rot = (_hash01(sv[:, None], part_ids[None, :], state.rounds)
+               * safe_cnt[sv].astype(jnp.float32)).astype(jnp.int32)
+        rank = jnp.where(safe_cnt[sv] > 0,
+                         (rank + rot) % safe_cnt[sv], rank)
+        contrib = elig_slot * (base[sv] + (rank < rem[sv]).astype(jnp.int32))
+        moved = cnt > 0
+        mv_left = jnp.where(moved, 0, mv)                            # [V, K]
 
-    # back to (u-side, v-side) order
-    e_pad = g.e_pad
-    contrib_uv = contrib[slots.inv]                                  # [2E, K]
-    cu, cv = contrib_uv[:e_pad], contrib_uv[e_pad:]                  # [E, K]
-    me = cu + cv                                                     # committed
+        # back to (u-side, v-side) order
+        e_pad = g.e_pad
+        contrib_uv = contrib[slots.inv]                              # [2E, K]
+        cu, cv = contrib_uv[:e_pad], contrib_uv[e_pad:]              # [E, K]
+        me = cu + cv                                                 # committed
 
     # ---- step 2: auction --------------------------------------------------
-    tie = _hash01(jnp.arange(e_pad, dtype=jnp.int32)[:, None],
-                  part_ids[None, :], state.rounds)
-    score = me.astype(jnp.float32) + tie
-    best = jnp.argmax(score, axis=1).astype(jnp.int32)               # [E]
-    best_amt = jnp.take_along_axis(me, best[:, None], axis=1)[:, 0]
-    can_buy = (best_amt >= 1) & emask
-    bought_free = free & can_buy
-    if cfg.variant_c:
-        best_is_poor = poor[best]
-        steal = (~free) & can_buy & best_is_poor & (best != owner) & rich_edge
-        paid = bought_free | steal
-    else:
-        paid = bought_free
-    new_owner = jnp.where(paid, best, owner)
+    with jax.named_scope("dfep.auction"):
+        tie = _hash01(jnp.arange(e_pad, dtype=jnp.int32)[:, None],
+                      part_ids[None, :], state.rounds)
+        score = me.astype(jnp.float32) + tie
+        best = jnp.argmax(score, axis=1).astype(jnp.int32)           # [E]
+        best_amt = jnp.take_along_axis(me, best[:, None], axis=1)[:, 0]
+        can_buy = (best_amt >= 1) & emask
+        bought_free = free & can_buy
+        if cfg.variant_c:
+            best_is_poor = poor[best]
+            steal = ((~free) & can_buy & best_is_poor & (best != owner)
+                     & rich_edge)
+            paid = bought_free | steal
+        else:
+            paid = bought_free
+        new_owner = jnp.where(paid, best, owner)
 
-    now_owned = new_owner[:, None] == part_ids[None, :]              # [E, K]
-    pay = (paid[:, None] & now_owned).astype(jnp.int32)
-    residual = me - pay                                              # [E, K] int
+        now_owned = new_owner[:, None] == part_ids[None, :]          # [E, K]
+        pay = (paid[:, None] & now_owned).astype(jnp.int32)
+        residual = me - pay                                          # [E, K] int
 
-    # winner residual: half/half (odd unit to u). losers: equal over funders
-    fu = (cu > 0).astype(jnp.int32)
-    fv = (cv > 0).astype(jnp.int32)
-    funders = jnp.maximum(fu + fv, 1)
-    half = residual // 2
-    loser_share = residual // funders
-    loser_rem = residual - loser_share * funders                     # 0 or 1
-    ref_u = jnp.where(now_owned, half + (residual - 2 * half),
-                      fu * (loser_share + loser_rem * fu))
-    ref_v = jnp.where(now_owned, half,
-                      fv * jnp.where(fu > 0, loser_share, loser_share + loser_rem))
-    mv_new = mv_left.at[u].add(ref_u).at[v].add(ref_v)
+        # winner residual: half/half (odd unit to u). losers: equal over
+        # funders
+        fu = (cu > 0).astype(jnp.int32)
+        fv = (cv > 0).astype(jnp.int32)
+        funders = jnp.maximum(fu + fv, 1)
+        half = residual // 2
+        loser_share = residual // funders
+        loser_rem = residual - loser_share * funders                 # 0 or 1
+        ref_u = jnp.where(now_owned, half + (residual - 2 * half),
+                          fu * (loser_share + loser_rem * fu))
+        ref_v = jnp.where(now_owned, half,
+                          fv * jnp.where(fu > 0, loser_share,
+                                         loser_share + loser_rem))
+        mv_new = mv_left.at[u].add(ref_u).at[v].add(ref_v)
+        progressed = jnp.sum(jnp.where(paid, 1, 0)) > 0
 
     # ---- step 3: coordinator grants (replicated, O(K)) --------------------
-    # grant_i = min(cap, ceil(|E| / size_i)) — "inversely proportional to the
-    # number of edges already bought", with the paper's cap (10) binding for
-    # any partition smaller than |E|/cap (i.e. for most of the run, which is
-    # what makes the cap meaningful).
-    sizes = _sizes(new_owner, k)
-    remaining = jnp.sum(jnp.where(new_owner == FREE, 1, 0))
-    grant = jnp.minimum(jnp.int32(cfg.cap),
-                        -(-jnp.int32(g.n_edges) // jnp.maximum(sizes, 1)))
-    grant = jnp.where(remaining > 0, grant, 0)                       # [K]
+    with jax.named_scope("dfep.grant"):
+        # grant_i = min(cap, ceil(|E| / size_i)) — "inversely proportional to
+        # the number of edges already bought", with the paper's cap (10)
+        # binding for any partition smaller than |E|/cap (i.e. for most of
+        # the run, which is what makes the cap meaningful).
+        sizes = _sizes(new_owner, k)
+        remaining = jnp.sum(jnp.where(new_owner == FREE, 1, 0))
+        grant = jnp.minimum(jnp.int32(cfg.cap),
+                            -(-jnp.int32(g.n_edges) // jnp.maximum(sizes, 1)))
+        grant = jnp.where(remaining > 0, grant, 0)                   # [K]
 
-    # distribute over the vertices where the partition *committed* funding to
-    # a still-free edge this round (its active frontier); if it has no such
-    # vertex, fall back to its full presence set.
-    still_free = new_owner == FREE                                   # [E]
-    fr_u = jnp.zeros((g.n_vertices, k), jnp.bool_)
-    fr_u = fr_u.at[u].max((cu > 0) & still_free[:, None])
-    fr_u = fr_u.at[v].max((cv > 0) & still_free[:, None])
-    presence = mv_new > 0                                            # [V, K]
-    owned_at = jnp.zeros((g.n_vertices, k), jnp.bool_)
-    owned_mask = now_owned & emask[:, None]
-    owned_at = owned_at.at[u].max(owned_mask).at[v].max(owned_mask)
-    presence = presence | owned_at
-    has_frontier = jnp.any(fr_u, axis=0)                             # [K]
-    presence = jnp.where(has_frontier[None, :], fr_u, presence)
-    if grant_v is not None:   # local re-auction: grants stay in the region
-        presence = presence & grant_v[:, None]
-    pres_i = presence.astype(jnp.int32)
-    n_pres = jnp.maximum(jnp.sum(pres_i, axis=0), 1)                 # [K]
-    p_base = grant // n_pres
-    p_rem = grant - p_base * n_pres                                  # [K]
-    p_rank = jnp.cumsum(pres_i, axis=0) - pres_i                     # [V, K]
-    p_rot = (_hash01(jnp.full((1,), 7, jnp.int32), part_ids[None, :],
-                     state.rounds) * n_pres.astype(jnp.float32)).astype(jnp.int32)
-    p_rank = (p_rank + p_rot) % n_pres[None, :]
-    mv_new = mv_new + pres_i * (p_base[None, :]
-                                + (p_rank < p_rem[None, :]).astype(jnp.int32))
+        # distribute over the vertices where the partition *committed*
+        # funding to a still-free edge this round (its active frontier); if
+        # it has no such vertex, fall back to its full presence set.
+        still_free = new_owner == FREE                               # [E]
+        fr_u = jnp.zeros((g.n_vertices, k), jnp.bool_)
+        fr_u = fr_u.at[u].max((cu > 0) & still_free[:, None])
+        fr_u = fr_u.at[v].max((cv > 0) & still_free[:, None])
+        presence = mv_new > 0                                        # [V, K]
+        owned_at = jnp.zeros((g.n_vertices, k), jnp.bool_)
+        owned_mask = now_owned & emask[:, None]
+        owned_at = owned_at.at[u].max(owned_mask).at[v].max(owned_mask)
+        presence = presence | owned_at
+        has_frontier = jnp.any(fr_u, axis=0)                         # [K]
+        presence = jnp.where(has_frontier[None, :], fr_u, presence)
+        if grant_v is not None:   # local re-auction: grants stay in the region
+            presence = presence & grant_v[:, None]
+        pres_i = presence.astype(jnp.int32)
+        n_pres = jnp.maximum(jnp.sum(pres_i, axis=0), 1)             # [K]
+        p_base = grant // n_pres
+        p_rem = grant - p_base * n_pres                              # [K]
+        p_rank = jnp.cumsum(pres_i, axis=0) - pres_i                 # [V, K]
+        p_rot = (_hash01(jnp.full((1,), 7, jnp.int32), part_ids[None, :],
+                         state.rounds)
+                 * n_pres.astype(jnp.float32)).astype(jnp.int32)
+        p_rank = (p_rank + p_rot) % n_pres[None, :]
+        mv_new = mv_new + pres_i * (
+            p_base[None, :] + (p_rank < p_rem[None, :]).astype(jnp.int32))
 
-    progressed = jnp.sum(jnp.where(paid, 1, 0)) > 0
     return DfepState(
         owner=new_owner,
         mv=mv_new,
@@ -368,16 +376,35 @@ def finalize(g: Graph, owner: jax.Array, k: int, iters: int = 64) -> jax.Array:
 def partition(g: Graph, k: int, key: jax.Array | int = 0,
               variant_c: bool = False, slots: Slots | None = None,
               **kw) -> tuple[jax.Array, dict]:
-    """Convenience wrapper: run DFEP and return (owner [E], info dict)."""
+    """Convenience wrapper: run DFEP and return (owner [E], info dict).
+
+    Recorded as a ``dfep.partition`` span (``k``, ``rounds``,
+    ``finalized``) with children ``dfep.slots`` (the host slot layout and
+    its upload), ``dfep.run`` (dispatch of the round loop to its sync) and
+    ``dfep.finalize``; counter ``dfep.rounds``."""
+    from .. import obs   # core's one use of obs (LP003 suppressed here)
+    rec = obs.get()
+    psid = rec.begin("dfep.partition", k=k)
     if isinstance(key, int):
         key = jax.random.key(key)
     if slots is None:
+        sid = rec.begin("dfep.slots", parent=psid)
         slots = build_slots(g)
+        rec.end(sid)
     cfg = DfepConfig(k=k, variant_c=variant_c, **kw)
+    sid = rec.begin("dfep.run", parent=psid)
     st = run_dfep(g, slots, cfg, key)
+    rounds = int(st.rounds)
+    rec.end(sid)
     unsold = int(jnp.sum(jnp.where(st.owner == FREE, 1, 0)))
-    owner = finalize(g, st.owner, k) if unsold else st.owner
+    owner = st.owner
+    if unsold:
+        sid = rec.begin("dfep.finalize", parent=psid)
+        owner = finalize(g, owner, k)
+        rec.end(sid)
     owner = jnp.where(g.edge_mask, owner, -2)
-    info = {"rounds": int(st.rounds), "unsold_at_stop": unsold,
+    info = {"rounds": rounds, "unsold_at_stop": unsold,
             "finalized": bool(unsold)}
+    rec.end(psid, rounds=rounds, finalized=bool(unsold))
+    rec.counter("dfep.rounds", rounds)
     return owner, info

@@ -157,6 +157,7 @@ def _seg_call(flags, vals, *, combine: str, block_s: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((s, k), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, k), jnp.float32)],
         interpret=interpret,
+        name="segment_scan",
     )(flags, vals)
 
 
@@ -314,6 +315,7 @@ def _gspmm_call(flags, mask, w, vals, *, combine: str, block_s: int,
         out_shape=jax.ShapeDtypeStruct((s, kf), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, kf), jnp.float32)],
         interpret=interpret,
+        name="gspmm",
     )(flags, mask, w, vals)
 
 
@@ -451,6 +453,7 @@ def _update_call(state, incoming, vmask, replicated, *, combine: str,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((k_pad, v_pad), jnp.float32),
         interpret=interpret,
+        name="masked_update",
     )(state, incoming, vmask, replicated)
 
 

@@ -40,12 +40,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import obs as _obs
@@ -163,17 +162,7 @@ class PendingResult:
             jax.block_until_ready(self._arrays)
         ex = self.exchange_per_superstep
         steps = int(jnp.max(supersteps))
-        rec = _obs.get()
-        if rec.enabled:   # per-dispatch superstep + exchange accounting
-            # numpy on the already-synced host arrays: a jnp reduction here
-            # would dispatch a fresh XLA computation per served result and
-            # show up as recorder overhead
-            rec.event("engine.result", supersteps=steps,
-                      local_iters=int(np.max(np.asarray(local_iters))),
-                      converged=bool(np.all(np.asarray(converged))),
-                      exchange_per_superstep=ex, exchanged=steps * ex)
-            rec.counter("engine.supersteps", steps)
-            rec.counter("engine.exchanged", steps * ex)
+        _obs.get().counter("engine.supersteps", steps)
         return EngineResult(state, supersteps, local_iters, converged, ex,
                             steps * ex)
 
@@ -201,6 +190,20 @@ def _expand(mask: jax.Array, ref: jax.Array) -> jax.Array:
     return mask[:, :, None] if ref.ndim == 3 else mask
 
 
+def _scoped(name: str):
+    """Trace the decorated function inside ``jax.named_scope(name)``, so
+    its device ops carry the name in a profiler trace; a scope object of
+    its own per call (one is not re-entrant)."""
+    def wrap(fn):
+        @wraps(fn)
+        def scoped(*args, **kw):
+            with jax.named_scope(name):
+                return fn(*args, **kw)
+        return scoped
+    return wrap
+
+
+@_scoped("engine.sweep")
 def _sweep(plan, prog, state, ctx, *, use_pallas: bool):
     """One Gather-Apply sweep: per-target aggregate [K, Vmax(, F)]."""
     pre = prog.pre(state, ctx)                              # [K, Vmax(, F)]
@@ -219,6 +222,7 @@ def _sweep(plan, prog, state, ctx, *, use_pallas: bool):
     return kernels.segment_reduce_ref(plan, msgs, prog.combine)
 
 
+@_scoped("engine.exchange")
 def _exchange(plan, values, combine, axis: str | None, *,
               use_pallas: bool):
     """Combine replicated slots across partitions; private slots unchanged.
@@ -254,6 +258,7 @@ def _exchange(plan, values, combine, axis: str | None, *,
     return jnp.where(_expand(plan.vmask, values), new, ident)
 
 
+@_scoped("engine.gather")
 def _gather_global(plan, state, axis: str | None):
     """Master-slot scatter of the final local states to a global [V(, F)]."""
     tail = state.shape[2:]
@@ -296,9 +301,11 @@ def _run_loop(plan: PartitionPlan, prog: EdgeProgram, kw: dict,
         def local_phase(st):
             def body(c):
                 s, it, _ = c
-                agg = _sweep(plan, prog, s, ctx, use_pallas=use_pallas)
-                ns = prog.apply(s, agg, ctx)
-                return ns, it + 1, jnp.any(ns != s)
+                # the apply and its test fuse with the sweep's last gather
+                with jax.named_scope("engine.sweep"):
+                    agg = _sweep(plan, prog, s, ctx, use_pallas=use_pallas)
+                    ns = prog.apply(s, agg, ctx)
+                    return ns, it + 1, jnp.any(ns != s)
 
             if not prog.local_fixpoint:
                 s, it, _ = body((st, jnp.int32(0), True))
@@ -327,7 +334,8 @@ def _run_loop(plan: PartitionPlan, prog: EdgeProgram, kw: dict,
             agg = _sweep(plan, prog, st, ctx, use_pallas=use_pallas)
             agg_full = _exchange(plan, agg, prog.combine, axis,
                                  use_pallas=use_pallas)
-            return prog.apply(st, agg_full, ctx), None
+            with jax.named_scope("engine.sweep"):     # its apply half
+                return prog.apply(st, agg_full, ctx), None
 
         st, _ = jax.lax.scan(superstep, state0, None, length=max_supersteps)
         steps = jnp.int32(max_supersteps)
@@ -499,8 +507,15 @@ class Engine:
     def run(self, prog: EdgeProgram, max_supersteps: int | None = None,
             max_local_iters: int = 100_000, warm_state=None,
             **kw: Any) -> EngineResult:
-        return self.dispatch(prog, max_supersteps, max_local_iters,
-                             warm_state=warm_state, **kw).result()
+        """``dispatch`` and sync, as one ``engine.run`` span (``program``,
+        ``supersteps``)."""
+        rec = _obs.get()
+        sid = rec.begin("engine.run", program=prog.name)
+        res = self.dispatch(prog, max_supersteps, max_local_iters,
+                            warm_state=warm_state, **kw).result()
+        if sid is not None:
+            rec.end(sid, supersteps=int(res.supersteps))
+        return res
 
     def dispatch_batched(self, prog: EdgeProgram, batched_kw: dict,
                          max_supersteps: int | None = None,

@@ -64,6 +64,29 @@ _BATCH_DTYPES = {int: jnp.int32, float: jnp.float32}
 _SERVER_IDS = itertools.count()   # obs provider names: serve0, serve1, ...
 
 
+class _TimedLock:
+    """The server's re-entrant lock; while the recorder is enabled, each
+    wait to acquire it is a ``serve.lock_wait`` span on the waiting
+    thread."""
+
+    __slots__ = ("_rlock",)
+
+    def __init__(self):
+        self._rlock = threading.RLock()
+
+    def __enter__(self):
+        rec = _obs.get()
+        if not rec.enabled:
+            self._rlock.acquire()
+            return
+        sid = rec.begin("serve.lock_wait")
+        self._rlock.acquire()
+        rec.end(sid)
+
+    def __exit__(self, *exc):
+        self._rlock.release()
+
+
 def _frozen(a: np.ndarray) -> np.ndarray:
     """Mark an array read-only. Served values and cache entries are shared
     across tenants (and with the cache itself); a tenant mutating its
@@ -181,7 +204,7 @@ class GraphServer:
         self.metrics = ServeMetrics()
         self.cache = ResultCache(cache_entries)
         self._batcher = MicroBatcher(self.buckets)
-        self._lock = threading.RLock()
+        self._lock = _TimedLock()
         self._t_submit: dict[int, float] = {}
         # bounded: callers that keep ids around collect via result(); old
         # completed entries age out instead of leaking on long-lived servers
@@ -570,6 +593,8 @@ class GraphServer:
         if pending is not None:
             self.metrics.record_batch(len(batch.requests) - len(cached),
                                       n_lanes, bucket, len(warm_lanes))
+            rec.counter("serve.lanes", n_lanes)
+            rec.counter("serve.bucket_lanes", bucket)
         return _InFlight(batch, buffer, pending, lane_of, cached,
                          n_lanes, bucket, time.perf_counter(), warm_lanes,
                          span=bsid, cost=cost)
@@ -591,10 +616,14 @@ class GraphServer:
             # state block: the denominator every ledger device_s and
             # utilization figure reconciles against (device_time_s)
             t_exec = time.perf_counter()
+            sid = rec.begin("engine.sync", parent=esid)
             res = fl.pending.result()
+            rec.end(sid)
+            sid = rec.begin("serve.fetch", parent=esid)
             state = np.asarray(res.state)
             ss = np.asarray(res.supersteps).reshape(-1)
             iters = np.asarray(res.local_iters).reshape(-1)
+            rec.end(sid)
             exec_dt = time.perf_counter() - t_exec
             self.metrics.record_execute(exec_dt)
             # the cost model is per-sweep (every loop clamped to one

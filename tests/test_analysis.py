@@ -112,6 +112,21 @@ def test_suppression_symbol_glob_narrows():
     assert all(f.symbol != "Widget.refresh" for f in kept)
 
 
+def test_import_layering_names_the_importing_function():
+    """An import inside a function is reported under that function, so a
+    suppression can allow it there alone and keep the module's other
+    imports flagged."""
+    findings = [f for f in scan([str(FIXTURES / "lp003_bad.py")])
+                if f.rule == "LP003"]
+    assert sorted(f.symbol for f in findings) == [
+        "<module>", "<module>", "<module>", "traced_partition"]
+    supps = parse("LP003 *lp003_bad.py traced_partition -- one wrapper\n",
+                  all_rules())
+    kept, silenced = apply_suppressions(findings, supps)
+    assert [f.symbol for f in silenced] == ["traced_partition"]
+    assert len(kept) == 3
+
+
 def test_unknown_rule_id_is_an_error():
     with pytest.raises(SuppressionError, match="unknown rule id"):
         parse("ZZ999 foo.py -- whatever\n", all_rules())

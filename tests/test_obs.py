@@ -1,9 +1,12 @@
 """repro.obs correctness: ring-buffer wraparound, the disabled no-op
 contract, span-tree connectivity across a served micro-batch (admission ->
 batch -> dispatch -> execute -> materialize), retrace events on a forced
-bucket-shape change, export round-trips (JSONL + Chrome trace schema), and
-partition-health gauges matching the core metrics after a stream patch."""
+bucket-shape change, export round-trips (JSONL + Chrome trace schema),
+partition-health gauges matching the core metrics after a stream patch,
+and the program's spans, counters and named scopes as a JAX profiler
+trace sees them."""
 import json
+import pathlib
 import time
 
 import numpy as np
@@ -15,6 +18,7 @@ from repro import gserve as G
 from repro import obs
 from repro import stream as S
 from repro.engine import runtime
+from repro.obs import recorder as recorder_mod
 from repro.obs.recorder import Recorder
 
 
@@ -192,7 +196,13 @@ def test_served_batch_span_tree_connected():
             assert e["args"]["parent_id"] in ids, stage
     # engine-level dispatch events rode along underneath
     assert len(by_name["engine.dispatch"]) == 2
-    assert len(by_name["engine.result"]) == 2
+    # a single-job Engine.run is one engine.run span naming its program
+    # and supersteps
+    res = srv.front.engine.run(E.SSSP, source=1)
+    run = [e for e in rec.events() if e["name"] == "engine.run"]
+    assert len(run) == 1
+    assert run[0]["args"]["program"] == "sssp"
+    assert run[0]["args"]["supersteps"] == int(res.supersteps) > 0
     assert rec.stats()["open_spans"] == 0
     srv.close()
 
@@ -436,3 +446,129 @@ def test_raising_provider_reported_not_fatal():
 # enforced repo-wide by the LP002 AST rule (repro.analysis) via
 # tests/test_analysis.py::test_repo_scans_clean — alias-aware, unlike the
 # grep-mirroring test that used to live here.
+
+
+# ---------------------------------------------------------------------------
+# the program's spans in a profiler trace; counters; named scopes
+# ---------------------------------------------------------------------------
+
+class _CountingMark:
+    made = 0
+
+    def __init__(self, name):
+        type(self).made += 1
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_disabled_recorder_creates_no_annotation(monkeypatch):
+    monkeypatch.setattr(recorder_mod, "TraceAnnotation", _CountingMark)
+    _CountingMark.made = 0
+    r = Recorder()
+    r.end(r.begin("never"))
+    with r.span("never"):
+        pass
+    assert _CountingMark.made == 0
+    r.enable()
+    r.end(r.begin("once"))
+    with r.span("twice"):
+        pass
+    assert _CountingMark.made == 2
+
+
+def _host_spans(trace_dir) -> dict:
+    """name -> [(start_ns, end_ns, line)] of the trace's host events."""
+    from jax.profiler import ProfileData
+
+    found = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
+    pd = ProfileData.from_file(str(found[-1]))
+    out: dict = {}
+    for plane in pd.planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for li, line in enumerate(plane.lines):
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns,
+                     (plane.name, li)))
+    return out
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    import jax
+
+    g, srv = _served_server()
+    rec = obs.get()
+    rec.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        dfep.partition(g, k=4, key=1)
+        # two programs, so the drain pipelines two batches: the second
+        # batch span opens before the first one closes
+        srv.serve([G.QueryRequest("sssp", params={"source": 1}),
+                   G.QueryRequest("wcc")])
+    finally:
+        jax.profiler.stop_trace()
+    srv.close()
+    got = _host_spans(tmp_path)
+    for name in ("dfep.partition", "dfep.slots", "dfep.run",
+                 "serve.admission", "serve.lock_wait", "serve.batch",
+                 "serve.dispatch", "serve.execute", "engine.sync",
+                 "serve.fetch", "serve.materialize"):
+        assert name in got, name
+    ring = {}
+    for e in rec.events():
+        if e.get("ph") == "X":
+            ring.setdefault(e["name"], []).append(e)
+    for name in ("dfep.partition", "serve.batch", "serve.fetch"):
+        assert len(got[name]) == len(ring[name]), name
+    # the batches interleave on one thread, each with its own extent
+    (s0, e0, l0), (s1, e1, l1) = sorted(got["serve.batch"])
+    assert l0 == l1 and s0 < s1 < e0 < e1
+    durs = sorted(e["dur"] for e in ring["serve.batch"])
+    assert sorted((e - s) / 1e3 for s, e, _ in got["serve.batch"]) == \
+        pytest.approx(durs, rel=0.2, abs=200)
+    # children lie inside their parents on the profiler's clock
+    (ps, pe, _), = got["dfep.partition"]
+    for child in ("dfep.slots", "dfep.run"):
+        (cs, ce, _), = got[child]
+        assert ps <= cs <= ce <= pe
+    assert rec.stats()["open_spans"] == 0
+
+
+def test_partition_and_serve_counters():
+    g, srv = _served_server()
+    rec = obs.get()
+    rec.enable()
+    _, info = dfep.partition(g, k=4, key=2)
+    part = [e for e in rec.events() if e["name"] == "dfep.partition"]
+    assert part[0]["args"]["rounds"] == info["rounds"]
+    assert part[0]["args"]["finalized"] == info["finalized"]
+    # three sssp lanes pad to a bucket of four
+    srv.serve([G.QueryRequest("sssp", params={"source": s})
+               for s in (1, 2, 3)])
+    srv.close()
+    c = rec.counters()
+    assert c["dfep.rounds"] == info["rounds"]
+    assert c["serve.lanes"] == 3
+    assert c["serve.bucket_lanes"] == G.bucket_for(3, srv.buckets) > 3
+
+
+def test_named_scopes_reach_the_hlo():
+    g = graph.watts_strogatz(60, 4, 0.2, seed=1)
+    import jax
+
+    cfg = dfep.DfepConfig(k=4)
+    text = dfep.run_dfep.lower(g, dfep.build_slots(g), cfg,
+                               jax.random.key(0)).compile().as_text()
+    for scope in ("dfep.spread", "dfep.auction", "dfep.grant"):
+        assert f"/{scope}/" in text, scope
+    owner, _ = dfep.partition(g, k=4, key=0)
+    eng = E.Engine(E.compile_plan(g, np.asarray(owner), 4))
+    text = eng.lower_hlo(E.SSSP, source=jax.numpy.int32(0))
+    for scope in ("engine.sweep", "engine.exchange", "engine.gather"):
+        assert f"/{scope}/" in text, scope

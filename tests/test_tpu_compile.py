@@ -3,7 +3,8 @@
 Nothing runs: each test lowers a kernel entry point for one chip of a
 described ``v5e:2x2`` topology, with ``ShapeDtypeStruct`` operands at the
 ``dblp`` (scale 1.0, k=8) plan shapes, and asserts that Mosaic compiled the
-kernel (a ``tpu_custom_call`` in the executable).  This is what the
+kernel (a ``tpu_custom_call`` in the executable) under its own name, which
+is how a profiler trace tells the kernels apart.  This is what the
 interpreter cannot show: a kernel body the TPU compiler refuses, or tiles
 that overflow the scoped VMEM at real widths.
 
@@ -13,6 +14,7 @@ cannot be described.
 """
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,16 +58,20 @@ def _struct(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(lowered):
+def _assert_kernel(lowered, name):
     text = lowered.compile().as_text()
-    assert 'custom_call_target="tpu_custom_call"' in text
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    assert all(re.search(rf"%{name}(\.\d+)? = ", c) for c in calls)
 
 
 @pytest.mark.parametrize("combine", ["min", "add"])
 def test_segment_scan_compiles_for_v5e(one_chip, combine):
     flags = _struct(one_chip, (E_MAX, K), jnp.bool_)
     vals = _struct(one_chip, (E_MAX, K), jnp.float32)
-    _assert_kernel(kernels.segment_scan.lower(flags, vals, combine=combine))
+    _assert_kernel(kernels.segment_scan.lower(flags, vals, combine=combine),
+                   "segment_scan")
 
 
 @pytest.mark.parametrize("features", [4, 128])
@@ -77,7 +83,7 @@ def test_gspmm_scan_compiles_for_v5e(one_chip, features):
     weights = _struct(one_chip, (E_MAX, k_pad), jnp.float32)
     vals = _struct(one_chip, (E_MAX, k_pad * features), jnp.float32)
     _assert_kernel(kernels._gspmm_scan.lower(flags, flags, weights, vals,
-                                             combine="add"))
+                                             combine="add"), "gspmm")
 
 
 @pytest.mark.parametrize("combine", ["min", "add"])
@@ -85,4 +91,5 @@ def test_masked_update_compiles_for_v5e(one_chip, combine):
     state = _struct(one_chip, (K, V_MAX), jnp.float32)
     mask = _struct(one_chip, (K, V_MAX), jnp.bool_)
     _assert_kernel(kernels.masked_update.lower(state, state, mask, mask,
-                                               combine=combine))
+                                               combine=combine),
+                   "masked_update")
