@@ -16,6 +16,9 @@ Three kernels, all specialized to the ``PartitionPlan`` CSR blocks:
     picks each vertex's aggregate out of the scanned stream at
     ``plan.last_slot`` (a plain gather; padding slots hold the identity
     because the padding region starts a fresh identity-valued segment).
+    Half-edges the streaming patch path appended after the sorted prefix
+    are scatter-combined on top, in a ``lax.cond`` branch taken only when
+    the plan's append region holds a live half-edge.
 
 ``gspmm``
     The fused GNN hot path (PR 10): gather neighbour feature rows,
@@ -82,6 +85,33 @@ def _scatter_combine(tgt: jax.Array, rows: jax.Array, cols: jax.Array,
     if combine == "max":
         return at.max(vals)
     return at.add(vals)
+
+
+def _fold_append_region(plan, agg: jax.Array, messages, combine: str
+                        ) -> jax.Array:
+    """Combine the append region's live half-edges into ``agg``.
+
+    The streaming patch path appends half-edges into ``[csr_fill, e_max)``
+    in arbitrary order, each its own segment, so they are scatter-combined
+    into the scanned aggregate ``agg`` [K, Vmax, F].  ``messages()`` builds
+    the [K, Emax, F] message stream; it is called inside a ``lax.cond``
+    branch taken only when some partition's append region holds a live
+    half-edge, so a plan fresh from ``compile_plan`` (or one whose appended
+    edges are all deleted) pays neither the stream nor the scatter.  The
+    predicate reads the plan's dynamic children, so a patched plan keeps
+    the same trace.  The branch covers every partition at once: slots that
+    are masked or in a CSR prefix are pinned to the identity there."""
+    ident = _IDENTITY[combine]
+    k, e_max = plan.emask.shape
+    slot = jnp.arange(e_max, dtype=jnp.int32)[None, :]
+    appended = plan.emask & (slot >= plan.csr_fill[:, None])     # [K, Emax]
+
+    def fold(agg):
+        rows = jnp.arange(k, dtype=jnp.int32)[:, None]
+        slack = jnp.where(appended[:, :, None], messages(), ident)
+        return _scatter_combine(agg, rows, plan.edge_tgt, slack, combine)
+
+    return jax.lax.cond(jnp.any(appended), fold, lambda agg: agg, agg)
 
 
 def _pallas_on_platform(call, *args):
@@ -197,8 +227,10 @@ def segment_reduce(plan, messages: jax.Array,
     ``[0, csr_fill)`` of each lane; half-edges appended by the streaming
     patch path live in ``[csr_fill, e_max)`` in arbitrary order, so their
     contribution is combined by a masked scatter on top of the scanned
-    aggregate.  Masked (deleted/padding) slots are pinned to the combine
-    identity in both regions and are therefore inert for every combine.
+    aggregate, run only when some lane's append region holds a live
+    half-edge (:func:`_fold_append_region`).  Masked (deleted/padding)
+    slots are pinned to the combine identity in both regions and are
+    therefore inert for every combine.
     """
     ident = _IDENTITY[combine]
     squeeze = messages.ndim == 2
@@ -213,9 +245,7 @@ def segment_reduce(plan, messages: jax.Array,
     scanned = scanned.reshape(e_max, k, f).transpose(1, 0, 2)    # [K, Emax, F]
     rows = jnp.arange(k, dtype=jnp.int32)[:, None]
     agg = scanned[rows, plan.last_slot]                          # [K, Vmax, F]
-    # append-region contributions (each appended half-edge is one segment)
-    slack = jnp.where((plan.emask & ~in_csr)[:, :, None], msgs3, ident)
-    agg = _scatter_combine(agg, rows, plan.edge_tgt, slack, combine)
+    agg = _fold_append_region(plan, agg, lambda: msgs3, combine)
     agg = jnp.where(plan.vmask[:, :, None], agg, ident)
     return agg[:, :, 0] if squeeze else agg
 
@@ -369,9 +399,10 @@ def gspmm(plan, feats: jax.Array, weights: jax.Array,
     The neighbour gather reuses the slack-aware ``plan.edge_nbr`` indices
     (maintained by the streaming patch path), the CSR prefix flows through
     ONE fused Pallas multiply+scan pass, and append-region half-edges are
-    folded in by the same masked scatter as :func:`segment_reduce` — so
-    the result is exact under in-place plan patches.  Partitions are
-    padded so K·F stays a multiple of the 128-lane tile.
+    folded in by the same masked scatter as :func:`segment_reduce`, run
+    only when the region holds a live half-edge — so the result is exact
+    under in-place plan patches.  Partitions are padded so K·F stays a
+    multiple of the 128-lane tile.
     """
     if combine == "sum":
         combine = "add"
@@ -406,10 +437,9 @@ def gspmm(plan, feats: jax.Array, weights: jax.Array,
     scanned = _gspmm_scan(flags, maskt, wop, vals, combine=combine)
     scanned = scanned.reshape(e_max, k_pad, f).transpose(1, 0, 2)[:k]
     agg = scanned[rows, plan.last_slot]                     # [K, Vmax, F]
-    # append-region half-edges: weighted outside the kernel (the region is
-    # a small bounded slack), combined by the same masked scatter
-    slack = jnp.where((plan.emask & ~in_csr)[:, :, None], msgs * w3, ident)
-    agg = _scatter_combine(agg, rows, plan.edge_tgt, slack, combine)
+    # append-region half-edges are weighted outside the kernel, and only
+    # when the region holds one
+    agg = _fold_append_region(plan, agg, lambda: msgs * w3, combine)
     return jnp.where(plan.vmask[:, :, None], agg, ident)
 
 

@@ -463,11 +463,15 @@ class Engine:
                 "(the previous epoch's finalized result state)")
         return prev
 
-    def _obs_dispatch(self, prog: EdgeProgram, bucket: int):
+    def _obs_dispatch(self, prog: EdgeProgram, bucket: int,
+                      pallas: bool = False):
         """Per-dispatch telemetry: records the dispatch event (program,
         bucket, plan epoch, exchange volume, lane occupancy) and returns an
         ambient-tag context so any jit retrace triggered while tracing
-        inside it is attributed to this program + bucket shape."""
+        inside it is attributed to this program + bucket shape.  A dispatch
+        on the Pallas kernels (``pallas``) whose plan holds appended
+        half-edges also counts ``engine.append_scatters``: the kernels run
+        their append-region scatter only then."""
         rec = _obs.get()
         if not rec.enabled:
             return contextlib.nullcontext()
@@ -479,6 +483,8 @@ class Engine:
                   vertex_lane_occupancy_max=
                       health["vertex_lane_occupancy_max"])
         rec.counter("engine.dispatches")
+        if pallas and health["append_live_half_edges"] > 0:
+            rec.counter("engine.append_scatters")
         for name, value in health.items():
             rec.gauge(f"plan.{name}", value)
         return rec.tags(program=prog.name, bucket=bucket)
@@ -492,8 +498,9 @@ class Engine:
         steps = _steps(prog, max_supersteps)
         prev = self._check_warm(prog, warm_state, None)
         kw = {k: jnp.asarray(v) for k, v in kw.items()}
-        with self._obs_dispatch(prog, 0):
-            if self.mesh is None:
+        single = self.mesh is None
+        with self._obs_dispatch(prog, 0, pallas=single and self.use_pallas):
+            if single:
                 out = _run_single(self.plan, prog, kw, prev, steps,
                                   max_local_iters, self.use_pallas)
             else:

@@ -35,6 +35,8 @@ def plan_health(plan) -> dict:
     norm = sizes / mean
     csr_fill = np.asarray(plan.csr_fill).astype(np.float64)     # [K]
     v_fill = np.asarray(plan.v_fill).astype(np.float64)         # [K]
+    slot = np.arange(plan.e_max)[None, :]
+    appended = np.asarray(plan.emask) & (slot >= csr_fill[:, None])
     health = {
         # the paper's axes
         "replication_factor": float(plan.replication_factor()),
@@ -50,6 +52,9 @@ def plan_health(plan) -> dict:
         "vertex_lane_occupancy_max": float((v_fill / plan.v_max).max()),
         "min_free_edge_slots": int((plan.e_max - csr_fill).min()),
         "min_free_vertex_slots": int((plan.v_max - v_fill).min()),
+        # live half-edges past the sorted CSR prefix: the kernels fold the
+        # append region in only while this is above 0
+        "append_live_half_edges": int(appended.sum()),
     }
     object.__setattr__(plan, "_obs_health", health)
     return health

@@ -254,6 +254,84 @@ def test_segment_reduce_matches_reference(graphs):
                                    rtol=1e-6)
 
 
+@pytest.fixture(scope="module")
+def append_plans():
+    """One graph's plan fresh from ``compile_plan`` and patched with
+    inserted edges: spread over every partition, and into partition 0
+    alone (the append fold then runs for the whole plan while one
+    partition holds appended half-edges)."""
+    from repro.stream.patch import EdgeChange, patch_plan
+    g = graph.watts_strogatz(90, 4, 0.2, seed=2)
+    fresh = E.compile_plan(g, baselines.hash_partition(g, 3), 3,
+                           edge_slack=16, vertex_slack=8)
+    u, v = g.as_numpy()
+    have = {(int(a), int(b)) for a, b in zip(u, v)}
+    rng = np.random.default_rng(7)
+    pairs = []
+    while len(pairs) < 6:
+        a, b = sorted(int(x) for x in rng.choice(g.n_vertices, 2,
+                                                 replace=False))
+        if (a, b) not in have and (a, b) not in pairs:
+            pairs.append((a, b))
+    spread = patch_plan(fresh, [EdgeChange(a, b, -1, i % 3)
+                                for i, (a, b) in enumerate(pairs)])
+    one = patch_plan(fresh, [EdgeChange(a, b, -1, 0) for a, b in pairs[:2]])
+    return {"fresh": (fresh, 0), "patched": (spread, 6),
+            "patched_one_partition": (one, 2)}
+
+
+def _append_case(plan, kernel, combine, width):
+    """(program's kernel output, XLA reference) for random messages or
+    features; ``width`` None is the scalar stream."""
+    import jax
+    from repro.engine import kernels
+    shape = () if width is None else (width,)
+    if kernel == "segment_reduce":
+        msgs = jax.random.uniform(jax.random.key(3),
+                                  plan.emask.shape + shape, jnp.float32,
+                                  0.5, 10.0)
+        return (kernels.segment_reduce(plan, msgs, combine),
+                kernels.segment_reduce_ref(plan, msgs, combine))
+    feats = jax.random.normal(jax.random.key(4),
+                              (plan.n_vertices,) + shape, jnp.float32)
+    local = kernels.gather_vertex_channel(plan, feats)
+    if width is None:
+        local = local[:, :, 0]
+    return (kernels.gspmm(plan, local, plan.edge_w, combine),
+            kernels.gspmm_ref(plan, local, plan.edge_w, combine))
+
+
+_ADD_TOL = {"segment_reduce": {"rtol": 1e-6},
+            "gspmm": {"rtol": 1e-5, "atol": 1e-5}}
+
+
+@pytest.mark.parametrize("plan_kind", ["fresh", "patched",
+                                       "patched_one_partition"])
+@pytest.mark.parametrize("width", [None, 4])
+@pytest.mark.parametrize("combine", ["min", "add", "max"])
+@pytest.mark.parametrize("kernel", ["segment_reduce", "gspmm"])
+def test_append_region_fold_matches_reference(append_plans, kernel, combine,
+                                              width, plan_kind):
+    """The Pallas path folds the append region in only when it holds a live
+    half-edge: fresh plans skip it, patched plans take it, and both match
+    the XLA scatter reference (min/max exactly, add to rounding).  Where
+    only partition 0 holds appended half-edges, the other partitions read
+    exactly what the fresh plan gives them."""
+    from repro.obs.health import plan_health
+    plan, n_appended = append_plans[plan_kind]
+    assert plan_health(plan)["append_live_half_edges"] == 2 * n_appended
+    got, want = (np.asarray(x) for x in _append_case(plan, kernel, combine,
+                                                     width))
+    if combine == "add":       # the tolerances of each kernel's older tests
+        np.testing.assert_allclose(got, want, **_ADD_TOL[kernel])
+    else:
+        np.testing.assert_array_equal(got, want)
+    if plan_kind == "patched_one_partition":
+        base, _ = _append_case(append_plans["fresh"][0], kernel, combine,
+                               width)
+        np.testing.assert_array_equal(got[1:], np.asarray(base)[1:])
+
+
 def test_superstep_cap_reports_nonconvergence():
     """Hitting max_supersteps is surfaced instead of silently truncating."""
     n = 60  # path graph with alternating edge ownership: slow cut crossings

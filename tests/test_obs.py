@@ -334,6 +334,41 @@ def test_health_gauges_match_plan_metrics_after_patch():
     assert any(e["name"] == "stream.apply" for e in rec.events())
 
 
+def test_append_scatters_count_dispatches_on_appended_plans():
+    """``engine.append_scatters`` counts the single-device Pallas dispatches
+    whose plan holds appended half-edges, and
+    ``plan.append_live_half_edges`` counts those half-edges: two for each
+    inserted edge still live."""
+    g = graph.watts_strogatz(150, 4, 0.2, seed=1)
+    sess = S.StreamSession(g, S.StreamConfig(k=4, chunk_size=64,
+                                             drift_threshold=1e9), key=0)
+    rec = obs.get()
+    rec.enable()
+    E.engine_sssp(sess.engine, 0)
+    E.engine_wcc(sess.engine)
+    assert rec.counters()["engine.dispatches"] == 2
+    assert rec.counters().get("engine.append_scatters", 0) == 0
+    assert rec.gauges()["plan.append_live_half_edges"] == 0
+
+    u, v = g.as_numpy()
+    have = {(int(a), int(b)) for a, b in zip(u, v)}
+    new = [(a, b) for a, b in ((0, 75), (3, 90), (10, 120))
+           if (a, b) not in have]
+    assert len(new) == 3
+    sess.apply(inserts=np.array(new))
+    E.engine_sssp(sess.engine, 0)
+    assert rec.counters()["engine.append_scatters"] == 1
+    assert rec.gauges()["plan.append_live_half_edges"] == 2 * 3
+    sess.apply(deletes=np.array(new[:1]))
+    E.engine_sssp(sess.engine, 0)
+    E.engine_wcc(sess.engine)
+    E.multi_source_sssp(sess.engine, [0, 5])      # batched: no Pallas path
+    c = rec.counters()
+    assert c["engine.dispatches"] == 6
+    assert c["engine.append_scatters"] == 3
+    assert rec.gauges()["plan.append_live_half_edges"] == 2 * 2
+
+
 def test_compaction_event_carries_new_epoch():
     g = graph.watts_strogatz(120, 4, 0.2, seed=3)
     sess = S.StreamSession(g, S.StreamConfig(k=3, chunk_size=32,
