@@ -28,7 +28,7 @@ Three kernels, all specialized to the ``PartitionPlan`` CSR blocks:
     pass over the edge stream ([BLK_S, K·F] VMEM tiles, partitions
     major / features minor on the lane axis), so the weighted message
     stream is never materialised to HBM between them.  ``gspmm_ref`` is
-    the unfused XLA scatter reference (and the shard_map-path
+    the unfused XLA scatter reference (and the batched-path
     implementation).
 
 ``masked_update``
@@ -294,7 +294,7 @@ def gather_edge_channel(plan, values: jax.Array, fill: float = 0.0
 
 def segment_reduce_ref(plan, messages: jax.Array,
                        combine: str = "min") -> jax.Array:
-    """XLA scatter reference (also the shard_map-path implementation).
+    """XLA scatter reference (also the batched-path implementation).
 
     Accepts [K, Emax] or [K, Emax, F] messages like :func:`segment_reduce`.
     """
@@ -447,7 +447,7 @@ def gspmm_ref(plan, feats: jax.Array, weights: jax.Array,
               combine: str = "add") -> jax.Array:
     """Unfused XLA reference for :func:`gspmm`: gather, materialise the
     weighted message stream, scatter segment-reduce (also the
-    shard_map-path implementation)."""
+    batched-path implementation)."""
     if combine == "sum":
         combine = "add"
     if combine == "mean":

@@ -22,10 +22,12 @@ Two device mappings, same numerics:
     axis; segment-reduce runs in the Pallas kernel (compiled on TPU,
     interpreted on the CPU backend);
   * **shard_map** — partitions are sharded over a 1-d device mesh axis
-    (``K % n_devices == 0``, each device holds a [K/D, ...] block); the
-    exchange's cross-partition combine becomes a device-local scatter
-    followed by ``lax.pmin``/``psum`` over the mesh axis.  Collectives sit
-    only in the exchange, so local fixed-point loops run fully
+    (``K % n_devices == 0``, each device holds a [K/D, ...] block and runs
+    the same kernels over it); the exchange's cross-partition combine
+    becomes a device-local scatter followed by ``lax.pmin``/``psum`` over
+    the mesh axis.  Collectives sit only in the exchange, the superstep's
+    convergence test and the final gather, each under the
+    ``engine.collective`` scope, so local fixed-point loops run fully
     device-local, exactly like the paper's workers between
     synchronisations.
 
@@ -203,6 +205,14 @@ def _scoped(name: str):
     return wrap
 
 
+def _across(op, x: jax.Array, axis: str) -> jax.Array:
+    """``op`` (``lax.pmin``/``pmax``/``psum``) over the mesh axis: every
+    cross-chip collective goes through here, under the
+    ``engine.collective`` scope a profiler trace finds it by."""
+    with jax.named_scope("engine.collective"):
+        return op(x, axis)
+
+
 @_scoped("engine.sweep")
 def _sweep(plan, prog, state, ctx, *, use_pallas: bool):
     """One Gather-Apply sweep: per-target aggregate [K, Vmax(, F)]."""
@@ -241,15 +251,15 @@ def _exchange(plan, values, combine, axis: str | None, *,
     if combine == "min":
         glob = glob.at[flat_idx].min(flat_send)
         if axis is not None:
-            glob = jax.lax.pmin(glob, axis)
+            glob = _across(jax.lax.pmin, glob, axis)
     elif combine == "max":
         glob = glob.at[flat_idx].max(flat_send)
         if axis is not None:
-            glob = jax.lax.pmax(glob, axis)
+            glob = _across(jax.lax.pmax, glob, axis)
     else:  # add identity is 0.0, so the masked send scatters exactly
         glob = glob.at[flat_idx].add(flat_send)
         if axis is not None:
-            glob = jax.lax.psum(glob, axis)
+            glob = _across(jax.lax.psum, glob, axis)
     inc = glob[plan.local2global]                           # [K, Vmax(, F)]
     if use_pallas:
         return kernels.masked_update(values, inc, plan.vmask, plan.replicated,
@@ -270,8 +280,8 @@ def _gather_global(plan, state, axis: str | None):
     present = present.at[plan.local2global.reshape(-1)].max(
         plan.is_master.reshape(-1))
     if axis is not None:
-        out = jax.lax.psum(out, axis)
-        present = jax.lax.psum(present.astype(jnp.int32), axis) > 0
+        out = _across(jax.lax.psum, out, axis)
+        present = _across(jax.lax.psum, present.astype(jnp.int32), axis) > 0
     return out, present
 
 
@@ -322,7 +332,8 @@ def _run_loop(plan: PartitionPlan, prog: EdgeProgram, kw: dict,
                             use_pallas=use_pallas)
             changed = jnp.any(st2 != st)
             if axis is not None:
-                changed = jax.lax.pmax(changed.astype(jnp.int32), axis) > 0
+                changed = _across(jax.lax.pmax, changed.astype(jnp.int32),
+                                  axis) > 0
             return st2, steps + 1, litot + li, changed
 
         st, steps, litot, changed = jax.lax.while_loop(
@@ -343,7 +354,7 @@ def _run_loop(plan: PartitionPlan, prog: EdgeProgram, kw: dict,
         converged = jnp.bool_(True)   # fixed-iteration programs by design
 
     if axis is not None:  # local sweep counts diverge per device: report the
-        litot = jax.lax.pmax(litot, axis)  # critical path, as documented
+        litot = _across(jax.lax.pmax, litot, axis)  # critical path
     glob, present = _gather_global(plan, st, axis)
     return prog.finalize(glob, present, plan, ctx), steps, litot, converged
 
@@ -357,11 +368,14 @@ def _run_single(plan, prog, kw, prev, max_supersteps, max_local_iters,
 
 
 @partial(jax.jit, static_argnames=("prog", "mesh", "axis", "k_local",
-                                   "max_supersteps", "max_local_iters"))
+                                   "max_supersteps", "max_local_iters",
+                                   "use_pallas"))
 def _run_sharded(plan, kw, prev, *, prog, mesh, axis, k_local,
-                 max_supersteps, max_local_iters):
+                 max_supersteps, max_local_iters, use_pallas):
     """Module-level so repeated queries hit one jit cache entry per
-    (program, mesh, shape) — the serving path never retraces."""
+    (program, mesh, shape) — the serving path never retraces.  Each device
+    runs the superstep loop over its own [K/D, ...] block with the kernels
+    ``_run_single`` would run for the same ``Engine``."""
     plan_spec = jax.tree_util.tree_map(lambda _: P(axis), plan)
     kw_spec = jax.tree_util.tree_map(lambda _: P(), kw)
     prev_spec = jax.tree_util.tree_map(lambda _: P(), prev)
@@ -369,8 +383,7 @@ def _run_sharded(plan, kw, prev, *, prog, mesh, axis, k_local,
     def body(plan_local, kw_local, prev_local):
         plan_local = dataclasses.replace(plan_local, k=k_local)
         return _run_loop(plan_local, prog, kw_local, prev_local, axis,
-                         max_supersteps, max_local_iters,
-                         use_pallas=False)
+                         max_supersteps, max_local_iters, use_pallas)
 
     fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(plan_spec, kw_spec, prev_spec),
@@ -469,9 +482,10 @@ class Engine:
         bucket, plan epoch, exchange volume, lane occupancy) and returns an
         ambient-tag context so any jit retrace triggered while tracing
         inside it is attributed to this program + bucket shape.  A dispatch
-        on the Pallas kernels (``pallas``) whose plan holds appended
-        half-edges also counts ``engine.append_scatters``: the kernels run
-        their append-region scatter only then."""
+        on the Pallas kernels (``pallas``, on one device or a mesh) whose
+        plan holds appended half-edges also counts
+        ``engine.append_scatters``: the kernels run their append-region
+        scatter only then."""
         rec = _obs.get()
         if not rec.enabled:
             return contextlib.nullcontext()
@@ -498,9 +512,8 @@ class Engine:
         steps = _steps(prog, max_supersteps)
         prev = self._check_warm(prog, warm_state, None)
         kw = {k: jnp.asarray(v) for k, v in kw.items()}
-        single = self.mesh is None
-        with self._obs_dispatch(prog, 0, pallas=single and self.use_pallas):
-            if single:
+        with self._obs_dispatch(prog, 0, pallas=self.use_pallas):
+            if self.mesh is None:
                 out = _run_single(self.plan, prog, kw, prev, steps,
                                   max_local_iters, self.use_pallas)
             else:
@@ -508,7 +521,8 @@ class Engine:
                                    mesh=self.mesh, axis=self.axis,
                                    k_local=self._k_local(),
                                    max_supersteps=steps,
-                                   max_local_iters=max_local_iters)
+                                   max_local_iters=max_local_iters,
+                                   use_pallas=self.use_pallas)
         return PendingResult(out, self.plan.exchange_volume)
 
     def run(self, prog: EdgeProgram, max_supersteps: int | None = None,
@@ -601,7 +615,8 @@ class Engine:
                     self._sharded_plan(), kw, None, prog=prog,
                     mesh=self.mesh, axis=self.axis,
                     k_local=self._k_local(), max_supersteps=steps,
-                    max_local_iters=max_local_iters)
+                    max_local_iters=max_local_iters,
+                    use_pallas=self.use_pallas)
         else:
             batched_kw = {k: jnp.asarray(v) for k, v in batched_kw.items()}
             if self.mesh is None:

@@ -1,6 +1,7 @@
 """Engine shard_map path: partitions sharded over an 8-device host mesh in a
 subprocess (same pattern as test_distributed.py), checked against the
-whole-graph oracles and the single-device fallback."""
+whole-graph oracles and the single-device fallback; and the analytics
+programs on the platform's kernels over 4 devices, 2 partitions each."""
 import os
 import subprocess
 import sys
@@ -174,7 +175,61 @@ CHANNEL_SCRIPT = textwrap.dedent("""
 """)
 
 
-def _run_subprocess(script: str, marker: str) -> None:
+# The five programs of the analytics mix, k = 8 on 4 devices (the
+# four-chip deployment's layout), each on the sharded path with the
+# kernels the platform runs: checked against the plain references and the
+# single-device engine.  Prints one line per program.
+ANALYTICS_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import numpy as np
+    import jax, jax.numpy as jnp
+
+    from repro.core import algorithms as alg
+    from repro.core import dfep, graph
+    from repro import engine as E
+
+    assert len(jax.devices()) == 4
+    g = graph.watts_strogatz(300, 6, 0.1, seed=2)
+    owner, _ = dfep.partition(g, k=8, key=0, max_rounds=400, stall_rounds=16)
+    plan = E.compile_plan(g, np.asarray(owner), 8)
+    mesh = jax.make_mesh((4,), ("parts",))
+    eng, one = E.Engine(plan, mesh=mesh), E.Engine(plan)
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(g.n_vertices, 8)).astype(np.float32)
+    w = rng.normal(size=(8, 4)).astype(np.float32)
+    deg = g.degrees()
+    cases = {
+        "sssp": (E.SSSP, {"source": jnp.int32(3)},
+                 np.asarray(alg.reference_sssp(g, 3)[0]), 0.0),
+        "bfs": (E.BFS, {"source": jnp.int32(3)},
+                alg.reference_bfs(g, 3), 0.0),
+        "wcc": (E.WCC, {}, np.asarray(alg.reference_cc(g)[0]), 0.0),
+        "pagerank": (E.PAGERANK, {"max_supersteps": 30, "degrees": deg},
+                     np.asarray(alg.reference_pagerank(g, iters=30)), 1e-5),
+        "gcn_layer": (E.GCN_LAYER, {"degrees": deg, "x": x, "weight": w},
+                      alg.reference_gcn_layer(g, x, w), 1e-5),
+    }
+    kernels = {"gcn_layer": ("gspmm", "masked_update")}
+    for name, (prog, kw, want, atol) in cases.items():
+        got = np.asarray(eng.run(prog, **kw).state)
+        alone = np.asarray(one.run(prog, **kw).state)
+        steps = kw.pop("max_supersteps", None)
+        text = eng.lower_hlo(prog, max_supersteps=steps, **kw)
+        ran = all(k in text for k in kernels.get(
+            name, ("segment_scan", "masked_update")))
+        exact = atol == 0.0
+        ok = (ran and got.shape == want.shape
+              and (np.array_equal(got, want) if exact
+                   else np.allclose(got, want, atol=atol))
+              and (np.array_equal(got, alone) if exact
+                   else np.allclose(got, alone, atol=atol)))
+        print("MESH_KERNELS", name, "ok" if ok else "FAILED", ran,
+              float(np.max(np.abs(got - want), initial=0.0)), flush=True)
+""")
+
+
+def _run_subprocess(script: str, marker: str) -> str:
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     env.pop("XLA_FLAGS", None)
@@ -184,6 +239,7 @@ def _run_subprocess(script: str, marker: str) -> None:
                              os.path.abspath(__file__))))
     assert marker in res.stdout, \
         f"stdout:\n{res.stdout}\nstderr:\n{res.stderr[-3000:]}"
+    return res.stdout
 
 
 @pytest.mark.slow
@@ -202,3 +258,23 @@ def test_engine_shard_map_channels():
     """Property channels on both shard_map paths: vertex planes through
     dispatch, an edge plane through dispatch_batched."""
     _run_subprocess(CHANNEL_SCRIPT, "ENGINE_DIST_CHANNELS_OK")
+
+
+@pytest.fixture(scope="module")
+def mesh_analytics():
+    """One subprocess runs all five programs; each test reads its line."""
+    return _run_subprocess(ANALYTICS_SCRIPT, "MESH_KERNELS")
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("program", ["sssp", "bfs", "wcc", "pagerank",
+                                     "gcn_layer"])
+def test_engine_shard_map_runs_the_platform_kernels(mesh_analytics,
+                                                    program):
+    """k = 8 over 4 devices on the kernels the platform runs (interpreted
+    here): exact for sssp, bfs and wcc, 1e-5 for pagerank and the GCN
+    layer, against the references and the single-device engine."""
+    line = next((ln for ln in mesh_analytics.splitlines()
+                 if ln.startswith(f"MESH_KERNELS {program} ")), None)
+    assert line is not None, mesh_analytics
+    assert line.split()[2] == "ok", line

@@ -369,6 +369,30 @@ def test_append_scatters_count_dispatches_on_appended_plans():
     assert rec.gauges()["plan.append_live_half_edges"] == 2 * 2
 
 
+def test_append_scatters_count_mesh_dispatches():
+    """The mesh path runs the kernels too, so its dispatches on a plan
+    with a live appended half-edge count ``engine.append_scatters`` (a
+    mesh of one device: the shard_map path in this process)."""
+    import jax
+    from jax.sharding import Mesh
+
+    g = graph.watts_strogatz(150, 4, 0.2, seed=1)
+    sess = S.StreamSession(g, S.StreamConfig(k=4, chunk_size=64,
+                                             drift_threshold=1e9), key=0)
+    u, v = g.as_numpy()
+    have = {(int(a), int(b)) for a, b in zip(u, v)}
+    assert (0, 75) not in have
+    sess.apply(inserts=np.array([(0, 75)]))
+    eng = E.Engine(sess.engine.plan,
+                   mesh=Mesh(jax.devices()[:1], ("parts",)))
+    rec = obs.get()
+    rec.enable()
+    r = E.engine_sssp(eng, 0)
+    assert rec.counters()["engine.append_scatters"] == 1
+    want = E.engine_sssp(sess.engine, 0)
+    assert np.array_equal(np.asarray(r.state), np.asarray(want.state))
+
+
 def test_compaction_event_carries_new_epoch():
     g = graph.watts_strogatz(120, 4, 0.2, seed=3)
     sess = S.StreamSession(g, S.StreamConfig(k=3, chunk_size=32,

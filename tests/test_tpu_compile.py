@@ -6,9 +6,11 @@ described ``v5e:2x2`` topology, with ``ShapeDtypeStruct`` operands at the
 kernel (a ``tpu_custom_call`` in the executable) under its own name, which
 is how a profiler trace tells the kernels apart.  This is what the
 interpreter cannot show: a kernel body the TPU compiler refuses, or tiles
-that overflow the scoped VMEM at real widths.  One more test lowers the
-whole single-device superstep loop at small plan shapes and reads where the
-compiler put the append-region scatter.
+that overflow the scoped VMEM at real widths.  Two more lower whole
+superstep loops at small plan shapes: the single-device loop, to read
+where the compiler put the append-region scatter, and the shard_map loop
+over all four chips, to see the kernels inside it and every cross-chip
+all-reduce under the ``engine.collective`` scope.
 
 The topology is described inside a module fixture, never at import (only
 one process at a time may load the TPU library), and the tests skip when it
@@ -21,6 +23,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro import engine as E
@@ -36,7 +39,7 @@ V_MAX = 124_672
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     prev_log = os.environ.get("TPU_LOG_DIR")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
@@ -51,11 +54,16 @@ def one_chip():
     prev_cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev_cache)
     compilation_cache.reset_cache()
     if prev_log is None:
         os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _struct(sharding, shape, dtype):
@@ -127,33 +135,42 @@ def _callers(comps: dict, attr: str) -> dict[str, set]:
     return out
 
 
-def test_append_scatter_runs_only_in_a_conditional_branch(one_chip):
-    """The Pallas sweep's scatter into the [K, Vmax] aggregate sits in a
-    ``conditional``'s branch, never straight in the superstep ``while``
-    body, so a plan with an empty append region skips it."""
-    k, e_max, v_max, n = 8, 1024, 256, 1500     # n != k·v_max
+# small plan shapes for the whole-loop compiles (n != k·v_max)
+SMALL_K, SMALL_E, SMALL_V, SMALL_N = 8, 1024, 256, 1500
+
+
+def _small_plan(sharding) -> PartitionPlan:
+    """Operand shapes of a small k = 8 plan, every leaf on ``sharding``."""
+    k, e_max, v_max = SMALL_K, SMALL_E, SMALL_V
     i32, b, f32 = jnp.int32, jnp.bool_, jnp.float32
 
     def kv(dtype):
-        return _struct(one_chip, (k, v_max), dtype)
+        return _struct(sharding, (k, v_max), dtype)
 
     def ke(dtype):
-        return _struct(one_chip, (k, e_max), dtype)
+        return _struct(sharding, (k, e_max), dtype)
 
     def kk():
-        return _struct(one_chip, (k,), i32)
+        return _struct(sharding, (k,), i32)
 
-    plan = PartitionPlan(
-        k=k, n_vertices=n, v_max=v_max, e_max=e_max, epoch=0, e_slots=4096,
-        local2global=kv(i32), vmask=kv(b), edge_tgt=ke(i32),
+    return PartitionPlan(
+        k=k, n_vertices=SMALL_N, v_max=v_max, e_max=e_max, epoch=0,
+        e_slots=4096, local2global=kv(i32), vmask=kv(b), edge_tgt=ke(i32),
         edge_nbr=ke(i32), emask=ke(b), seg_start=ke(b), last_slot=kv(i32),
         replicated=kv(b), is_master=kv(b), n_local=kk(), n_edges_local=kk(),
         n_replicated=kk(), csr_fill=kk(), v_fill=kk(), edge_w=ke(f32),
         edge_slot=ke(i32))
-    kw = {"source": _struct(one_chip, (), i32)}
+
+
+def test_append_scatter_runs_only_in_a_conditional_branch(one_chip):
+    """The Pallas sweep's scatter into the [K, Vmax] aggregate sits in a
+    ``conditional``'s branch, never straight in the superstep ``while``
+    body, so a plan with an empty append region skips it."""
+    k, v_max = SMALL_K, SMALL_V
+    kw = {"source": _struct(one_chip, (), jnp.int32)}
     text = runtime._run_single.lower(
-        plan, E.SSSP, kw, None, runtime._steps(E.SSSP, None), 100_000,
-        True).compile().as_text()
+        _small_plan(one_chip), E.SSSP, kw, None,
+        runtime._steps(E.SSSP, None), 100_000, True).compile().as_text()
     comps = _computations(text)
     fused_by = _callers(comps, "calls")
     scatters = [c for c, lines in comps.items()
@@ -169,3 +186,36 @@ def test_append_scatter_runs_only_in_a_conditional_branch(one_chip):
             owners.add(c)
     assert owners <= set(_callers(comps, "branch_computations"))
     assert not owners & set(_callers(comps, "body"))
+
+
+@pytest.mark.parametrize("program,kernel_names", [
+    ("sssp", {"segment_scan", "masked_update"}),
+    ("gcn_layer", {"gspmm", "masked_update"})])
+def test_sharded_loop_runs_the_kernels_on_four_chips(topo, program,
+                                                     kernel_names):
+    """The shard_map superstep loop over the four chips of a v5e:2x2, two
+    partitions a chip: Mosaic compiles the kernels inside it, and every
+    all-reduce the compiler emits sits under ``engine.collective``."""
+    mesh = Mesh(topo.devices, ("parts",))
+    rep = NamedSharding(mesh, P())
+    n, i32, f32 = SMALL_N, jnp.int32, jnp.float32
+    prog, kw = {
+        "sssp": (E.SSSP, {"source": _struct(rep, (), i32)}),
+        "gcn_layer": (E.GCN_LAYER, {
+            "degrees": _struct(rep, (n,), i32),
+            "x": _struct(rep, (n, 8), f32),
+            "weight": _struct(rep, (8, 4), f32)})}[program]
+    text = runtime._run_sharded.lower(
+        _small_plan(NamedSharding(mesh, P("parts"))), kw, None, prog=prog,
+        mesh=mesh, axis="parts", k_local=SMALL_K // 4,
+        max_supersteps=runtime._steps(prog, None), max_local_iters=100_000,
+        use_pallas=True).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    named = {m.group(1) for c in calls
+             for m in [re.search(r"%(\w+?)(\.\d+)? = ", c)] if m}
+    assert named == kernel_names
+    reduces = [line for line in text.splitlines()
+               if re.search(r" all-reduce(-start)?\(", line)]
+    assert reduces
+    assert all("/engine.collective/" in line for line in reduces)
