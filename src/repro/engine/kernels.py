@@ -18,7 +18,10 @@ Three kernels, all specialized to the ``PartitionPlan`` CSR blocks:
     because the padding region starts a fresh identity-valued segment).
     Half-edges the streaming patch path appended after the sorted prefix
     are scatter-combined on top, in a ``lax.cond`` branch taken only when
-    the plan's append region holds a live half-edge.
+    the plan's append region holds a live half-edge.  Under ``vmap`` (the
+    batched serving path) a ``custom_vmap`` rule folds the batch onto the
+    lane axis as a trailing feature axis, so a micro-batch of B queries
+    is one scan over K·B lanes.
 
 ``gspmm``
     The fused GNN hot path (PR 10): gather neighbour feature rows,
@@ -27,9 +30,9 @@ Three kernels, all specialized to the ``PartitionPlan`` CSR blocks:
     shape.  The multiply and the segmented combine run in ONE Pallas
     pass over the edge stream ([BLK_S, K·F] VMEM tiles, partitions
     major / features minor on the lane axis), so the weighted message
-    stream is never materialised to HBM between them.  ``gspmm_ref`` is
-    the unfused XLA scatter reference (and the batched-path
-    implementation).
+    stream is never materialised to HBM between them.  Under ``vmap`` the
+    batch folds onto the lane axis as it does for ``segment_reduce``.
+    ``gspmm_ref`` is the unfused XLA scatter reference.
 
 ``masked_update``
     The frontier/replica-update step of the exchange: replicated slots take
@@ -63,6 +66,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -214,6 +218,55 @@ def segment_scan(flags: jax.Array, vals: jax.Array,
     return out[:s, :k]
 
 
+class _Csr(NamedTuple):
+    """The plan arrays a segmented reduce reads (``edge_nbr`` only for
+    gspmm's gather), in the order the kernels first read them.  The
+    batching rules take these alone: a whole plan passed through
+    ``custom_vmap`` keeps every leaf live in the unbatched executable."""
+    edge_nbr: jax.Array | None
+    csr_fill: jax.Array
+    emask: jax.Array
+    seg_start: jax.Array
+    last_slot: jax.Array
+    edge_tgt: jax.Array
+    vmask: jax.Array
+
+
+def _csr(plan, gather: bool = False) -> _Csr:
+    return _Csr(plan.edge_nbr if gather else None, plan.csr_fill, plan.emask,
+                plan.seg_start, plan.last_slot, plan.edge_tgt, plan.vmask)
+
+
+def _planes4(x: jax.Array, batched: bool, size: int) -> jax.Array:
+    """A rule's operand as [B, K, N, F]: unbatched operands are broadcast,
+    scalar streams get F = 1."""
+    if not batched:
+        x = jnp.broadcast_to(x, (size,) + x.shape)
+    return x[..., None] if x.ndim == 3 else x
+
+
+def _to_lanes(x: jax.Array) -> jax.Array:
+    """[B, K, N, F] -> [K, N, F·B]: the batch becomes the minor part of a
+    trailing feature axis, so it lands on the kernels' lane axis next to
+    the partitions (partition-major, feature, then batch)."""
+    size, k, n, f = x.shape
+    return jnp.moveaxis(x, 0, -1).reshape(k, n, f * size)
+
+
+def _from_lanes(y: jax.Array, size: int) -> jax.Array:
+    """[K, N, F·B] -> [B, K, N, F], the inverse of :func:`_to_lanes`."""
+    k, n, fb = y.shape
+    return jnp.moveaxis(y.reshape(k, n, fb // size, size), -1, 0)
+
+
+def _vmapped_ref(ref, combine: str, size: int, in_batched, *args):
+    """The XLA twin under ``vmap``, for a call whose plan is batched."""
+    axes = jax.tree_util.tree_map(lambda b: 0 if b else None, in_batched)
+    out = jax.vmap(functools.partial(ref, combine=combine),
+                   in_axes=tuple(axes), axis_size=size)(*args)
+    return out, True
+
+
 def segment_reduce(plan, messages: jax.Array,
                    combine: str = "min") -> jax.Array:
     """Per-target aggregates over the plan's CSR stream.
@@ -222,6 +275,9 @@ def segment_reduce(plan, messages: jax.Array,
     aggregates [K, Vmax] / [K, Vmax, F] (identity at padding vertices).
     Feature planes fold onto the lane axis (partition-major,
     feature-minor), so the scalar case is exactly F=1 of the same scan.
+    Under ``vmap`` a batch of messages [B, K, Emax(, F)] over one plan
+    is the unbatched call on [K, Emax, F·B]: one scan for the batch.  A
+    batched plan falls back to the vmapped :func:`segment_reduce_ref`.
 
     Slack-aware bounds: the segmented scan covers only the sorted CSR prefix
     ``[0, csr_fill)`` of each lane; half-edges appended by the streaming
@@ -232,6 +288,31 @@ def segment_reduce(plan, messages: jax.Array,
     slots are pinned to the combine identity in both regions and are
     therefore inert for every combine.
     """
+    return _batched_segment_reduce(combine)(_csr(plan), messages)
+
+
+@functools.cache
+def _batched_segment_reduce(combine: str):
+    @jax.custom_batching.custom_vmap
+    def reduce(csr, messages):
+        return _segment_reduce(csr, messages, combine)
+
+    @reduce.def_vmap
+    def rule(size, in_batched, csr, messages):
+        csr_b, msgs_b = in_batched
+        if any(jax.tree_util.tree_leaves(csr_b)):
+            return _vmapped_ref(segment_reduce_ref, combine, size,
+                                in_batched, csr, messages)
+        scalar = messages.ndim == (3 if msgs_b else 2)
+        lanes = _to_lanes(_planes4(messages, msgs_b, size))
+        out = _from_lanes(_segment_reduce(csr, lanes, combine), size)
+        return (out[..., 0] if scalar else out), True
+
+    return reduce
+
+
+def _segment_reduce(plan: _Csr, messages: jax.Array,
+                    combine: str) -> jax.Array:
     ident = _IDENTITY[combine]
     squeeze = messages.ndim == 2
     msgs3 = messages[:, :, None] if squeeze else messages       # [K, Emax, F]
@@ -294,7 +375,7 @@ def gather_edge_channel(plan, values: jax.Array, fill: float = 0.0
 
 def segment_reduce_ref(plan, messages: jax.Array,
                        combine: str = "min") -> jax.Array:
-    """XLA scatter reference (also the batched-path implementation).
+    """XLA scatter reference for :func:`segment_reduce`.
 
     Accepts [K, Emax] or [K, Emax, F] messages like :func:`segment_reduce`.
     """
@@ -304,7 +385,8 @@ def segment_reduce_ref(plan, messages: jax.Array,
     msgs = jnp.where(plan.emask[:, :, None], msgs3, ident)
     k = plan.edge_tgt.shape[0]
     rows = jnp.arange(k, dtype=jnp.int32)[:, None]
-    out = jnp.full((k, plan.v_max, msgs3.shape[2]), ident, jnp.float32)
+    v_max = plan.vmask.shape[1]
+    out = jnp.full((k, v_max, msgs3.shape[2]), ident, jnp.float32)
     out = _scatter_combine(out, rows, plan.edge_tgt, msgs, combine)
     out = jnp.where(plan.vmask[:, :, None], out, ident)
     return out[:, :, 0] if squeeze else out
@@ -402,7 +484,10 @@ def gspmm(plan, feats: jax.Array, weights: jax.Array,
     folded in by the same masked scatter as :func:`segment_reduce`, run
     only when the region holds a live half-edge — so the result is exact
     under in-place plan patches.  Partitions are padded so K·F stays a
-    multiple of the 128-lane tile.
+    multiple of the 128-lane tile.  Under ``vmap`` the batch folds onto
+    the lane axis as in :func:`segment_reduce` (weights that vary by lane
+    fold with it); a batched plan falls back to the vmapped
+    :func:`gspmm_ref`.
     """
     if combine == "sum":
         combine = "add"
@@ -411,6 +496,34 @@ def gspmm(plan, feats: jax.Array, weights: jax.Array,
         cnt = segment_reduce(plan, jnp.ones(plan.emask.shape, jnp.float32),
                              "add")
         return s / jnp.maximum(cnt, 1.0)[:, :, None]
+    return _batched_gspmm(combine)(_csr(plan, gather=True), feats, weights)
+
+
+@functools.cache
+def _batched_gspmm(combine: str):
+    @jax.custom_batching.custom_vmap
+    def fused(csr, feats, weights):
+        return _gspmm(csr, feats, weights, combine)
+
+    @fused.def_vmap
+    def rule(size, in_batched, csr, feats, weights):
+        csr_b, feats_b, w_b = in_batched
+        if any(jax.tree_util.tree_leaves(csr_b)):
+            return _vmapped_ref(gspmm_ref, combine, size, in_batched, csr,
+                                feats, weights)
+        f4 = _planes4(feats, feats_b, size)                 # [B, K, Vmax, F]
+        if w_b or weights.ndim == 3:        # per-lane weights: fold them too
+            w4 = _planes4(weights, w_b, size)
+            weights = _to_lanes(jnp.broadcast_to(w4, w4.shape[:3]
+                                                 + f4.shape[3:]))
+        out = _gspmm(csr, _to_lanes(f4), weights, combine)
+        return _from_lanes(out, size), True
+
+    return fused
+
+
+def _gspmm(plan: _Csr, feats: jax.Array, weights: jax.Array,
+           combine: str) -> jax.Array:
     if feats.ndim == 2:
         feats = feats[:, :, None]
     k, e_max = plan.emask.shape
@@ -446,8 +559,7 @@ def gspmm(plan, feats: jax.Array, weights: jax.Array,
 def gspmm_ref(plan, feats: jax.Array, weights: jax.Array,
               combine: str = "add") -> jax.Array:
     """Unfused XLA reference for :func:`gspmm`: gather, materialise the
-    weighted message stream, scatter segment-reduce (also the
-    batched-path implementation)."""
+    weighted message stream, scatter segment-reduce."""
     if combine == "sum":
         combine = "add"
     if combine == "mean":

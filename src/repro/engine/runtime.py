@@ -34,9 +34,12 @@ Two device mappings, same numerics:
 Batched multi-source queries (the serving scenario) vmap the superstep
 loop over the source axis — one compiled program answers S queries in one
 superstep loop, on one device or with the batch axis vmapped inside the
-shard_map body.  ``dispatch``/``dispatch_batched`` return a
-``PendingResult`` without syncing so a serving scheduler can overlap batch
-formation with device execution (``jax.block_until_ready`` on completion).
+shard_map body.  On one device the kernels run under the vmap: the
+segmented reduce folds the batch onto its lane axis next to the
+partitions (``kernels.segment_reduce``'s batching rule).
+``dispatch``/``dispatch_batched`` return a ``PendingResult`` without
+syncing so a serving scheduler can overlap batch formation with device
+execution (``jax.block_until_ready`` on completion).
 """
 from __future__ import annotations
 
@@ -398,8 +401,9 @@ def _run_sharded_batched(plan, kw, batched_kw, prev, *, prog, mesh, axis,
     """Batched queries on the shard_map path: partitions stay sharded over
     the mesh axis while the batch axis is vmapped *inside* the sharded body,
     so one superstep loop answers the whole micro-batch with the same
-    collective schedule as the unbatched path (the XLA segment-reduce is
-    used — vmapping the Pallas grid is unsupported)."""
+    collective schedule as the unbatched path.  It runs the XLA scatter
+    twins: no benchmark cell serves batches over a mesh yet, so the
+    kernels' batching rule is untried here."""
     plan_spec = jax.tree_util.tree_map(lambda _: P(axis), plan)
     kw_spec = jax.tree_util.tree_map(lambda _: P(), kw)
     bkw_spec = jax.tree_util.tree_map(lambda _: P(), batched_kw)
@@ -485,7 +489,8 @@ class Engine:
         on the Pallas kernels (``pallas``, on one device or a mesh) whose
         plan holds appended half-edges also counts
         ``engine.append_scatters``: the kernels run their append-region
-        scatter only then."""
+        scatter only then.  Every dispatch on the kernels, single or
+        batched, counts ``engine.kernel_dispatches``."""
         rec = _obs.get()
         if not rec.enabled:
             return contextlib.nullcontext()
@@ -497,8 +502,10 @@ class Engine:
                   vertex_lane_occupancy_max=
                       health["vertex_lane_occupancy_max"])
         rec.counter("engine.dispatches")
-        if pallas and health["append_live_half_edges"] > 0:
-            rec.counter("engine.append_scatters")
+        if pallas:
+            rec.counter("engine.kernel_dispatches")
+            if health["append_live_half_edges"] > 0:
+                rec.counter("engine.append_scatters")
         for name, value in health.items():
             rec.gauge(f"plan.{name}", value)
         return rec.tags(program=prog.name, bucket=bucket)
@@ -544,9 +551,10 @@ class Engine:
                          **kw: Any) -> PendingResult:
         """Non-blocking micro-batch dispatch: vmap the superstep loop over a
         batch axis of ``batched_kw`` (e.g. ``{"source": sources}`` for
-        multi-source SSSP). Runs on one device or, with a mesh bound, with
-        the batch axis vmapped inside the shard_map body. The XLA
-        segment-reduce path is used (the Pallas kernels are not vmapped).
+        multi-source SSSP). On one device it runs the kernels
+        ``dispatch`` runs, the batch folded onto the segmented reduce's
+        lane axis; with a mesh bound, the batch axis is vmapped inside the
+        shard_map body over the XLA twins (``_run_sharded_batched``).
         The serving scheduler dispatches the next micro-batch while this
         one computes and syncs via ``.result()``.
         ``warm_state`` is a [S, V] block, one previous-result row per lane
@@ -556,20 +564,21 @@ class Engine:
         batched_kw = {k: jnp.asarray(v) for k, v in batched_kw.items()}
         n_batch = next(iter(batched_kw.values())).shape[0]
         prev = self._check_warm(prog, warm_state, n_batch)
-        with self._obs_dispatch(prog, n_batch):
+        pallas = self.use_pallas and self.mesh is None
+        with self._obs_dispatch(prog, n_batch, pallas=pallas):
             if self.mesh is None:
                 if prev is None:
                     def one(bkw):
                         return _run_single(self.plan, prog, {**kw, **bkw},
                                            None, steps, max_local_iters,
-                                           False)
+                                           self.use_pallas)
 
                     out = jax.vmap(one)(batched_kw)
                 else:
                     def one_warm(bkw, pv):
                         return _run_single(self.plan, prog, {**kw, **bkw},
                                            pv, steps, max_local_iters,
-                                           False)
+                                           self.use_pallas)
 
                     out = jax.vmap(one_warm)(batched_kw, prev)
             else:
@@ -626,7 +635,7 @@ class Engine:
                 def one(bkw):
                     return _run_single(self.plan, prog, {**kw, **bkw},
                                        None, steps, max_local_iters,
-                                       False)
+                                       self.use_pallas)
 
                 lowered = jax.jit(jax.vmap(one)).lower(batched_kw)
             else:

@@ -193,12 +193,11 @@ class GraphServer:
     def __init__(self, engine: Engine, graph: Graph, *,
                  buckets: tuple[int, ...] = DEFAULT_BUCKETS,
                  max_pending: int = 1024, cache_entries: int = 512,
-                 use_pallas: bool = False, max_wait_s: float | None = None,
+                 max_wait_s: float | None = None,
                  warm_entries: int = 256, monitor=None, ledger=None,
                  epoch: int = 0, version: int = 0):
         self.buckets = tuple(buckets)
         self.max_pending = int(max_pending)
-        self.use_pallas = bool(use_pallas)
         self.max_wait_s = max_wait_s
         self.monitor = monitor
         self.metrics = ServeMetrics()
@@ -283,11 +282,7 @@ class GraphServer:
     # -- plan double-buffering ----------------------------------------------
     def _make_buffer(self, engine: Engine, graph: Graph, epoch: int,
                      version: int) -> _PlanBuffer:
-        # serving runs the XLA segment-reduce path by default: batched
-        # dispatch requires it, and unbatched programs (WCC/PageRank) then
-        # share one code path with it (which path wins on the chip is
-        # for the benchmark to decide)
-        engine = dataclasses.replace(engine, use_pallas=self.use_pallas)
+        # the engine's kernel choice holds for batched and single queries
         return _PlanBuffer(engine, graph, int(epoch), int(version))
 
     def _on_plan_change(self, session, event: str) -> None:

@@ -332,6 +332,88 @@ def test_append_region_fold_matches_reference(append_plans, kernel, combine,
         np.testing.assert_array_equal(got[1:], np.asarray(base)[1:])
 
 
+@pytest.mark.parametrize("plan_kind", ["fresh", "patched"])
+@pytest.mark.parametrize("weights", ["plan", "per_feature", "batched"])
+@pytest.mark.parametrize("combine", ["add", "max", "mean"])
+def test_vmapped_gspmm_matches_vmapped_reference(append_plans, combine,
+                                                 weights, plan_kind):
+    """Under ``vmap`` gspmm folds the batch onto its lane axis beside the
+    features, with the plan's scalar weights, per-feature weights or
+    weights that vary by lane, and matches the vmapped XLA reference
+    (max exactly, add and mean to the tolerance of their older test)."""
+    import jax
+    from repro.engine import kernels
+    plan, _ = append_plans[plan_kind]
+    b, f = 3, 4
+    feats = jax.random.normal(jax.random.key(5), (b, plan.n_vertices, f))
+    local = jax.vmap(lambda x: kernels.gather_vertex_channel(plan, x))(feats)
+    shape = {"plan": None, "per_feature": plan.emask.shape + (f,),
+             "batched": (b,) + plan.emask.shape}[weights]
+    w = plan.edge_w if shape is None else jax.random.uniform(
+        jax.random.key(6), shape, jnp.float32, 0.5, 2.0)
+    axes = (0, 0 if weights == "batched" else None)
+    got, want = (np.asarray(jax.vmap(lambda x, w, fn=fn: fn(
+        plan, x, w, combine), in_axes=axes)(local, w))
+        for fn in (kernels.gspmm, kernels.gspmm_ref))
+    assert got.shape == (b,) + plan.vmask.shape + (f,)
+    if combine == "max":
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, **_ADD_TOL["gspmm"])
+
+
+def test_segment_reduce_over_batched_plans_runs_the_xla_twin(append_plans):
+    """A batch whose plan varies by lane cannot share one scan: the
+    batching rule falls back to the vmapped XLA reference."""
+    import jax
+    from repro.engine import kernels
+    plans = [append_plans[kind][0] for kind in ("fresh", "patched")]
+    stacked = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *plans)
+    msgs = jax.random.uniform(jax.random.key(7), (2,) + plans[0].emask.shape,
+                              jnp.float32, 0.5, 10.0)
+    got = jax.vmap(lambda p, m: kernels.segment_reduce(p, m, "min"))(
+        stacked, msgs)
+    for i, plan in enumerate(plans):
+        np.testing.assert_array_equal(
+            np.asarray(got[i]),
+            np.asarray(kernels.segment_reduce_ref(plan, msgs[i], "min")))
+
+
+_BATCHABLE = {"sssp": E.SSSP, "bfs": E.BFS, "wsssp": E.WEIGHTED_SSSP}
+
+
+@pytest.mark.parametrize("plan_kind", ["fresh", "patched"])
+@pytest.mark.parametrize("bucket", [1, 8, 32])
+@pytest.mark.parametrize("start", ["cold", "warm"])
+@pytest.mark.parametrize("program", list(_BATCHABLE))
+def test_batched_kernels_match_xla_twin_and_single_dispatch(
+        append_plans, program, start, bucket, plan_kind):
+    """``dispatch_batched`` on the kernels (the batch folded onto the
+    segmented scan's lanes) equals the vmapped XLA twin and one
+    ``dispatch`` per source, exactly, cold or warm; on the patched plan
+    the append-region branch runs under the batching rule."""
+    plan, _ = append_plans[plan_kind]
+    prog = _BATCHABLE[program]
+    sources = np.random.default_rng(bucket).choice(
+        plan.n_vertices, bucket, replace=False).astype(np.int32)
+    kernels, twin = E.Engine(plan), E.Engine(plan, use_pallas=False)
+    warm = None
+    if start == "warm":     # exact rows for even lanes, cold rows for odd
+        warm = np.array(twin.run_batched(prog, {"source": sources}).state)
+        warm[1::2] = prog.state.fill
+    got = kernels.run_batched(prog, {"source": sources}, warm_state=warm)
+    want = twin.run_batched(prog, {"source": sources}, warm_state=warm)
+    np.testing.assert_array_equal(np.asarray(got.state),
+                                  np.asarray(want.state))
+    np.testing.assert_array_equal(np.asarray(got.supersteps),
+                                  np.asarray(want.supersteps))
+    for i, src in enumerate(sources):
+        one = kernels.run(prog, source=jnp.int32(src),
+                          warm_state=None if warm is None else warm[i])
+        np.testing.assert_array_equal(np.asarray(one.state),
+                                      np.asarray(got.state[i]))
+
+
 def test_superstep_cap_reports_nonconvergence():
     """Hitting max_supersteps is surfaced instead of silently truncating."""
     n = 60  # path graph with alternating edge ownership: slow cut crossings

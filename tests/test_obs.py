@@ -362,11 +362,34 @@ def test_append_scatters_count_dispatches_on_appended_plans():
     sess.apply(deletes=np.array(new[:1]))
     E.engine_sssp(sess.engine, 0)
     E.engine_wcc(sess.engine)
-    E.multi_source_sssp(sess.engine, [0, 5])      # batched: no Pallas path
+    E.multi_source_sssp(sess.engine, [0, 5])      # batched: the kernels too
     c = rec.counters()
     assert c["engine.dispatches"] == 6
-    assert c["engine.append_scatters"] == 3
+    assert c["engine.append_scatters"] == 4
     assert rec.gauges()["plan.append_live_half_edges"] == 2 * 2
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_server_serves_on_its_engines_kernels(use_pallas):
+    """A ``GraphServer`` keeps its engine's kernel choice: each batched or
+    single dispatch counts ``engine.kernel_dispatches`` exactly when the
+    engine runs the kernels, and the answers match the oracles."""
+    g = graph.watts_strogatz(150, 4, 0.2, seed=3)
+    plan = E.compile_plan(g, np.asarray(dfep.partition(g, k=4, key=0)[0]), 4)
+    srv = G.GraphServer(E.Engine(plan, use_pallas=use_pallas), g,
+                        buckets=(1, 4))
+    rec = obs.get()
+    rec.enable()
+    results = srv.serve(
+        [G.QueryRequest("sssp", params={"source": s}) for s in (0, 5, 9, 17)]
+        + [G.QueryRequest("wcc")])
+    srv.close()
+    for r in results:
+        want = r.request.entry.oracle(g, **r.request.params)
+        np.testing.assert_array_equal(r.value, np.asarray(want))
+    c = rec.counters()
+    assert c["engine.dispatches"] == 2
+    assert c.get("engine.kernel_dispatches", 0) == (2 if use_pallas else 0)
 
 
 def test_append_scatters_count_mesh_dispatches():

@@ -6,11 +6,12 @@ described ``v5e:2x2`` topology, with ``ShapeDtypeStruct`` operands at the
 kernel (a ``tpu_custom_call`` in the executable) under its own name, which
 is how a profiler trace tells the kernels apart.  This is what the
 interpreter cannot show: a kernel body the TPU compiler refuses, or tiles
-that overflow the scoped VMEM at real widths.  Two more lower whole
+that overflow the scoped VMEM at real widths.  Three more lower whole
 superstep loops at small plan shapes: the single-device loop, to read
-where the compiler put the append-region scatter, and the shard_map loop
-over all four chips, to see the kernels inside it and every cross-chip
-all-reduce under the ``engine.collective`` scope.
+where the compiler put the append-region scatter; the vmapped loop of
+the serving path, to see the batch on the scan's lanes; and the
+shard_map loop over all four chips, to see the kernels inside it and
+every cross-chip all-reduce under the ``engine.collective`` scope.
 
 The topology is described inside a module fixture, never at import (only
 one process at a time may load the TPU library), and the tests skip when it
@@ -162,19 +163,13 @@ def _small_plan(sharding) -> PartitionPlan:
         edge_slot=ke(i32))
 
 
-def test_append_scatter_runs_only_in_a_conditional_branch(one_chip):
-    """The Pallas sweep's scatter into the [K, Vmax] aggregate sits in a
-    ``conditional``'s branch, never straight in the superstep ``while``
-    body, so a plan with an empty append region skips it."""
-    k, v_max = SMALL_K, SMALL_V
-    kw = {"source": _struct(one_chip, (), jnp.int32)}
-    text = runtime._run_single.lower(
-        _small_plan(one_chip), E.SSSP, kw, None,
-        runtime._steps(E.SSSP, None), 100_000, True).compile().as_text()
+def _assert_scatters_only_in_branches(text: str, shape: str) -> None:
+    """Every scatter into an ``f32[shape]`` aggregate sits in a
+    ``conditional``'s branch, never straight in a ``while`` body."""
     comps = _computations(text)
     fused_by = _callers(comps, "calls")
     scatters = [c for c, lines in comps.items()
-                if any(re.search(rf"= f32\[{k * v_max}\]\S* scatter\(", line)
+                if any(re.search(rf"= f32\[{shape}\]\S* scatter\(", line)
                        for line in lines)]
     assert scatters
     owners, todo = set(), list(scatters)
@@ -186,6 +181,59 @@ def test_append_scatter_runs_only_in_a_conditional_branch(one_chip):
             owners.add(c)
     assert owners <= set(_callers(comps, "branch_computations"))
     assert not owners & set(_callers(comps, "body"))
+
+
+def test_append_scatter_runs_only_in_a_conditional_branch(one_chip):
+    """The Pallas sweep's scatter into the [K, Vmax] aggregate sits in a
+    ``conditional``'s branch, never straight in the superstep ``while``
+    body, so a plan with an empty append region skips it."""
+    kw = {"source": _struct(one_chip, (), jnp.int32)}
+    text = runtime._run_single.lower(
+        _small_plan(one_chip), E.SSSP, kw, None,
+        runtime._steps(E.SSSP, None), 100_000, True).compile().as_text()
+    _assert_scatters_only_in_branches(text, str(SMALL_K * SMALL_V))
+
+
+def _gathers(text: str) -> list[tuple[int, int, str]]:
+    """(output elements, index tuples, line) of each ``gather``: every
+    index tuple fetches one ``slice_sizes`` block of the output."""
+    out = []
+    for line in text.splitlines():
+        m = re.search(r"= f32\[([\d,]+)\]\S* gather\(.*slice_sizes="
+                      r"\{([\d,]+)\}", line)
+        if m:
+            n = math.prod(int(d) for d in m.group(1).split(","))
+            out.append((n, n // math.prod(int(d) for d in
+                                         m.group(2).split(",")), line))
+    return out
+
+
+def test_batched_loop_folds_the_batch_onto_the_scan_lanes(one_chip):
+    """The vmapped sssp loop at bucket 32 (the serving path) runs
+    ``segment_scan`` over K·B lanes; its scatter into the [K·Vmax, B]
+    aggregate runs only in the append-region branch; and each vertex's
+    aggregate is one K·Vmax-index gather of B-wide rows, not B·K·Vmax
+    single values."""
+    k, v_max, b = SMALL_K, SMALL_V, 32
+
+    def batched(plan, sources):
+        return jax.vmap(lambda s: runtime._run_single(
+            plan, E.SSSP, {"source": s}, None, runtime._steps(E.SSSP, None),
+            100_000, True))(sources)
+
+    text = jax.jit(batched).lower(
+        _small_plan(one_chip),
+        _struct(one_chip, (b,), jnp.int32)).compile().as_text()
+    scans = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.search(r"%segment_scan(\.\d+)? = ", line)]
+    assert scans
+    assert all(re.search(rf"= f32\[\d+,{k * b}\]", line) for line in scans)
+    _assert_scatters_only_in_branches(text, f"{k * v_max},{b}")
+    per_vertex = [(idx, line) for n, idx, line in _gathers(text)
+                  if n == k * v_max * b]
+    assert any("engine.sweep" in line for _, line in per_vertex)
+    assert all(idx == k * v_max for idx, _ in per_vertex)
 
 
 @pytest.mark.parametrize("program,kernel_names", [
