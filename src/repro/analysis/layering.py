@@ -37,8 +37,7 @@ LAYERING: dict[str, tuple[str, ...]] = {
     "engine": ("stream", "gserve"),
     "stream": ("gserve",),
     "obs": ("gserve",),
-    "analysis": ("core", "engine", "stream", "gserve", "obs", "ckpt",
-                 "train", "launch"),
+    "analysis": ("core", "engine", "stream", "gserve", "obs"),
 }
 
 

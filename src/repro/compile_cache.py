@@ -1,6 +1,6 @@
 """Where JAX keeps its persistent compilation cache for the entry points.
 
-``chip_smoke.py`` and ``benchmarks/run.py`` call :func:`use_compile_cache`
+``chip_smoke.py`` and ``bench/run.py`` call :func:`use_compile_cache`
 before their first compile.  If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
 reads it itself and nothing is set here.  Otherwise the cache lives at one
 fixed, git-ignored path inside the checkout, ``<repo>/.jax_cache``: a

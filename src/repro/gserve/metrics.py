@@ -41,9 +41,9 @@ from ..obs.histogram import LogHistogram, WindowedHistogram
 # stable at bench qps, short enough to reflect "now" during an incident.
 SNAPSHOT_WINDOW_S = 10.0
 
-# Process-lifetime execute-span totals across every server ever created —
-# benchmarks/run.py prints the per-figure delta next to wall-clock, the
-# same way it attributes recorder events from lifetime counts.  The
+# Process-lifetime execute-span totals across every server ever created,
+# so a caller can attribute device time to a stretch of work from lifetime
+# deltas, as it attributes recorder events from lifetime counts.  The
 # windowed ring gives the same spend over a trailing window, so a live
 # summary can show "device time now" next to the monotone total.
 _EXEC_TOTALS = {"device_s": 0.0, "executes": 0}

@@ -7,14 +7,8 @@ way and the BlockSpecs are the TPU tiling).
 """
 from __future__ import annotations
 
-import jax
-
 from .frontier_min import frontier_min
 from .lane_cumsum import lane_cumsum
 from .minplus_sweep import minplus_sweep
 
 __all__ = ["lane_cumsum", "frontier_min", "minplus_sweep"]
-
-from .selective_scan import selective_scan  # noqa: E402,F401
-
-__all__.append("selective_scan")

@@ -37,26 +37,3 @@ def minplus_relax(dist: jnp.ndarray, src: jnp.ndarray, dst: jnp.ndarray,
     out = dist.at[dst].min(cu)
     out = out.at[src].min(cv)
     return out
-
-
-def selective_scan_ref(x, dt, b, c, a, d_skip):
-    """Sequential selective-scan oracle (same recurrence as ssm.py).
-
-    x/dt [B,S,Di]; b/c [B,S,N]; a [Di,N]; d_skip [Di] -> y [B,S,Di].
-    """
-    import jax
-
-    def step(h, inp):
-        x_t, dt_t, b_t, c_t = inp
-        decay = jnp.exp(-dt_t[:, :, None] * a[None])
-        inject = (dt_t * x_t)[:, :, None] * b_t[:, None, :]
-        h = decay * h + inject
-        y_t = jnp.sum(h * c_t[:, None, :], axis=-1) + x_t * d_skip[None]
-        return h, y_t
-
-    bsz, s, d_in = x.shape
-    h0 = jnp.zeros((bsz, d_in, a.shape[1]), jnp.float32)
-    xs = (jnp.moveaxis(x, 1, 0), jnp.moveaxis(dt, 1, 0),
-          jnp.moveaxis(b, 1, 0), jnp.moveaxis(c, 1, 0))
-    _, ys = jax.lax.scan(step, h0, xs)
-    return jnp.moveaxis(ys, 0, 1)

@@ -18,9 +18,8 @@ Bundles are **bounded**: at most ``max_bundles`` newest files are kept
 per directory (oldest deleted on each dump), so an alert storm cannot
 fill a disk.  ``arm(monitor)`` subscribes the dump to a ``Monitor``'s
 ``on_alert`` hook; CI arms it via the ``REPRO_FLIGHT_DIR`` environment
-variable (``benchmarks/run.py`` for bench figures, ``tests/conftest.py``
-for tier-1 failures) and uploads the directory as a workflow artifact
-when the job fails.
+variable (``tests/conftest.py`` for tier-1 failures) and uploads the
+directory as a workflow artifact when the job fails.
 
 Render a bundle with ``python -m repro.obs.report <bundle.json>``.
 

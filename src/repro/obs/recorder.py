@@ -29,8 +29,7 @@ Ring buffer
 a lifetime monotonic count (survives ``reset()``); ``dropped`` counts
 events that have been overwritten since the last reset, and
 ``overwritten`` is the lifetime monotone overwrite count — the silent-
-data-loss meter (``benchmarks/run.py --all`` prints its per-figure
-delta).
+data-loss meter.
 
 Spans
 -----
@@ -124,8 +123,8 @@ class Recorder:
 
     def reset(self) -> None:
         """Drop recorded events/counters/gauges (the lifetime count and the
-        registered providers survive — ``benchmarks/run.py`` attributes
-        events per figure from lifetime deltas across resets)."""
+        registered providers survive, so a caller can attribute events
+        to a stretch of work from lifetime deltas across resets)."""
         with self._lock:
             self._reset_state()
 

@@ -1,7 +1,7 @@
 """Incident report renderer: flight bundles / JSONL traces -> human text.
 
     python -m repro.obs.report experiments/flight/flight-*.json
-    python -m repro.obs.report experiments/bench/trace_obs.jsonl
+    python -m repro.obs.report trace_obs.jsonl
 
 Takes either a flight-recorder bundle (``flight.py``) or a raw JSONL
 trace (``export_jsonl``) and prints an incident summary: what fired (the

@@ -1,6 +1,6 @@
 """Usage-report renderer: a cost-ledger snapshot -> per-tenant tables.
 
-    python -m repro.obs.usage experiments/bench/usage_ledger.json
+    python -m repro.obs.usage usage_ledger.json
     python -m repro.obs.usage experiments/flight/flight-*.json --top 5
 
 Takes a ``CostLedger.dump()`` snapshot, a full ``obs.snapshot()`` record

@@ -1,1 +1,1 @@
-from .env import MeshEnv, env_from_mesh, get_env, logical_spec, set_env, shard, use_mesh  # noqa: F401
+from .env import auto_axes  # noqa: F401

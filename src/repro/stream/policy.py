@@ -22,9 +22,9 @@ graph-slot or partition-slack headroom could not absorb
 ``headroom_batches`` more bursts of the observed peak magnitude, and
 (b) recommends per-partition edge slack sized to the same burst headroom
 — so the forced recompile either never happens or is paid in the idle
-gap instead of mid-burst.  ``benchmarks/fig_stream.py`` measures the two
-policies head-to-head on a bursty workload (apply-latency p99 + forced
-recompile count).
+gap instead of mid-burst.  ``tests/test_stream.py`` runs the two
+policies head-to-head on a seeded bursty workload and counts their
+forced recompiles.
 """
 from __future__ import annotations
 

@@ -4,7 +4,7 @@
 The first cut of the engine instrumentation computed the convergence
 gauge with ``jnp.max`` while building the recorder event.  Every recorded
 superstep dispatched a fresh single-op XLA computation and
-``benchmarks/fig_obs.py`` blew its 3% overhead budget.  The fix reduced
+the served benchmark blew its 3% overhead budget.  The fix reduced
 with numpy on the already-synced host copy; TS001 must flag the original
 forever."""
 import jax.numpy as jnp

@@ -8,10 +8,17 @@ import jax.numpy as jnp
 from repro.core import dfep, graph, metrics
 from repro.core.etsch import compile_partitioning
 
+from kronecker_graph import graph500
 
-@pytest.fixture(scope="module")
-def small_graph():
-    return graph.watts_strogatz(600, 6, 0.1, seed=3)
+GRAPHS = {
+    "smallworld": lambda: graph.watts_strogatz(600, 6, 0.1, seed=3),
+    "graph500": graph500,
+}
+
+
+@pytest.fixture(scope="module", params=list(GRAPHS))
+def small_graph(request):
+    return GRAPHS[request.param]()
 
 
 @pytest.fixture(scope="module")

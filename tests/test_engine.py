@@ -12,12 +12,15 @@ from repro.core import algorithms as alg
 from repro.core import baselines, dfep, graph, metrics
 from repro import engine as E
 
+from kronecker_graph import graph500
+
 PROFILES = {
     "smallworld": lambda: graph.watts_strogatz(150, 4, 0.1, seed=1),
     "powerlaw": lambda: graph.largest_component(
         graph.barabasi_albert(120, 3, seed=2)),
     "road": lambda: graph.largest_component(
         graph.road_network(10, 12, 0.25, seed=3)),
+    "graph500": graph500,
 }
 
 PARTITIONERS = {

@@ -1,7 +1,8 @@
 """Engine shard_map path: partitions sharded over an 8-device host mesh in a
 subprocess (same pattern as test_distributed.py), checked against the
-whole-graph oracles and the single-device fallback; and the analytics
-programs on the platform's kernels over 4 devices, 2 partitions each."""
+whole-graph oracles and the single-device fallback; the analytics
+programs on the platform's kernels over 4 devices, 2 partitions each; and
+the batched serving path over 4 devices against single dispatches."""
 import os
 import subprocess
 import sys
@@ -229,6 +230,51 @@ ANALYTICS_SCRIPT = textwrap.dedent("""
 """)
 
 
+# The serving path over a mesh: dispatch_batched with k = 8 partitions on
+# 4 devices (the batched shard_map body), for each batchable program, cold
+# and warm, on the Graph 500 Kronecker graph.  Each lane must equal one
+# single-device dispatch of its source.  Prints one line per case.
+BATCHED_MESH_SCRIPT = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    sys.path.insert(0, "tests")
+    import numpy as np
+    import jax, jax.numpy as jnp
+
+    from repro.core import dfep
+    from repro import engine as E
+    from kronecker_graph import graph500
+
+    assert len(jax.devices()) == 4
+    g = graph500()
+    owner, _ = dfep.partition(g, k=8, key=0, max_rounds=400, stall_rounds=16)
+    plan = E.compile_plan(g, np.asarray(owner), 8)
+    mesh = jax.make_mesh((4,), ("parts",))
+    eng, one = E.Engine(plan, mesh=mesh), E.Engine(plan)
+    sources = np.random.default_rng(8).choice(
+        g.n_vertices, 8, replace=False).astype(np.int32)
+    progs = {"sssp": E.SSSP, "bfs": E.BFS, "wsssp": E.WEIGHTED_SSSP}
+    for name, prog in progs.items():
+        cold = [one.dispatch(prog, source=jnp.int32(s)).result()
+                for s in sources]
+        # warm: exact rows on even lanes, cold rows on odd ones
+        warm = np.stack([np.asarray(r.state) for r in cold])
+        warm[1::2] = prog.state.fill
+        for start, prev in (("cold", None), ("warm", warm)):
+            got = eng.dispatch_batched(prog, {"source": sources},
+                                       warm_state=prev).result()
+            want = cold if prev is None else [
+                one.dispatch(prog, source=jnp.int32(s),
+                             warm_state=prev[i]).result()
+                for i, s in enumerate(sources)]
+            ok = all(np.array_equal(np.asarray(got.state[i]),
+                                    np.asarray(w.state))
+                     for i, w in enumerate(want))
+            print("MESH_BATCHED", name, start, "ok" if ok else "FAILED",
+                  flush=True)
+""")
+
+
 def _run_subprocess(script: str, marker: str) -> str:
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
@@ -278,3 +324,21 @@ def test_engine_shard_map_runs_the_platform_kernels(mesh_analytics,
                  if ln.startswith(f"MESH_KERNELS {program} ")), None)
     assert line is not None, mesh_analytics
     assert line.split()[2] == "ok", line
+
+
+@pytest.fixture(scope="module")
+def mesh_batched():
+    """One subprocess runs every batched case; each test reads its line."""
+    return _run_subprocess(BATCHED_MESH_SCRIPT, "MESH_BATCHED")
+
+
+@pytest.mark.parametrize("start", ["cold", "warm"])
+@pytest.mark.parametrize("program", ["sssp", "bfs", "wsssp"])
+def test_engine_shard_map_batched_matches_single_dispatch(mesh_batched,
+                                                          program, start):
+    """The serving path over a mesh: k = 8 over 4 devices, a micro-batch
+    of 8 sources, exactly one single-device dispatch per source."""
+    line = next((ln for ln in mesh_batched.splitlines()
+                 if ln.startswith(f"MESH_BATCHED {program} {start} ")), None)
+    assert line is not None, mesh_batched
+    assert line.split()[3] == "ok", line

@@ -1,15 +1,12 @@
-"""Beyond-paper extensions: multi-source SSSP, k-core, gradient compression."""
+"""Beyond-paper extensions: multi-source SSSP and k-core."""
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import algorithms as alg
 from repro.core import dfep, graph
 from repro.core.etsch import compile_partitioning
-from repro.train import compress as C
 
 
 @pytest.fixture(scope="module")
@@ -46,38 +43,3 @@ def test_kcore_matches_reference(setup, k_core):
         np.add.at(deg, u[live], 1)
         np.add.at(deg, v[live], 1)
         assert (deg[core] >= k_core).all()
-
-
-def test_compress_roundtrip_accuracy():
-    x = jax.random.normal(jax.random.key(0), (1000,)) * 3.0
-    c = C.compress(x)
-    y = C.decompress(c, x.shape)
-    assert float(jnp.max(jnp.abs(x - y))) <= float(jnp.max(jnp.abs(x))) / 127.0 + 1e-6
-
-
-@given(seed=st.integers(0, 50), n=st.integers(1, 2000))
-@settings(max_examples=15, deadline=None)
-def test_compress_roundtrip_property(seed, n):
-    x = jax.random.normal(jax.random.key(seed), (n,))
-    y = C.decompress(C.compress(x), x.shape)
-    # per-block bound: |err| <= blockmax/127
-    assert float(jnp.max(jnp.abs(x - y))) <= float(jnp.max(jnp.abs(x))) / 127.0 + 1e-6
-
-
-def test_error_feedback_mean_converges():
-    """With error feedback, the time-average of the decompressed signal
-    converges to the true (constant) gradient despite quantisation."""
-    g = {"w": jnp.full((300,), 0.003)}   # tiny values vs block scale
-    err = C.init_error_state(g)
-    acc = jnp.zeros((300,))
-    steps = 50
-    for _ in range(steps):
-        d, err, _ = C.ef_compress_tree(g, err)
-        acc = acc + d["w"]
-    np.testing.assert_allclose(np.asarray(acc / steps),
-                               np.asarray(g["w"]), rtol=0.05)
-
-
-def test_compression_ratio():
-    g = {"a": jnp.zeros((4096, 128)), "b": jnp.zeros((999,))}
-    assert C.compression_ratio(g) > 3.5

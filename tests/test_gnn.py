@@ -103,9 +103,12 @@ def _gspmm_fixture(seed=0, f=8):
     return g, plan, kernels.gather_vertex_channel(plan, feats)
 
 
+@pytest.mark.parametrize("width", [8, 32, 128])
 @pytest.mark.parametrize("combine", ["add", "sum", "max", "mean"])
-def test_gspmm_matches_ref(combine):
-    g, plan, local = _gspmm_fixture()
+def test_gspmm_matches_ref(combine, width):
+    """The fused kernel against its reference at GNN feature widths; 128
+    fills the lane tile."""
+    g, plan, local = _gspmm_fixture(f=width)
     got = np.asarray(kernels.gspmm(plan, local, plan.edge_w, combine))
     ref = np.asarray(kernels.gspmm_ref(plan, local, plan.edge_w, combine))
     finite = np.isfinite(ref)

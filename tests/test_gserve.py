@@ -17,6 +17,7 @@ from repro import engine as E
 from repro import gserve as G
 from repro import stream as S
 from repro.engine import runtime
+from repro.engine.registry import DEFAULT_REGISTRY
 
 
 def _static_server(n=150, k=4, seed=3, **kw):
@@ -145,6 +146,58 @@ def test_serve_new_programs_registered_via_registry():
     r2 = srv.serve([G.QueryRequest("wsssp", tenant="z",
                                    params={"source": 3})])[0]
     assert r2.from_cache
+
+
+@pytest.fixture(scope="module")
+def catalogue_plan():
+    g = graph.watts_strogatz(150, 4, 0.2, seed=3)
+    owner, _ = dfep.partition(g, k=4, key=0)
+    return g, E.compile_plan(g, np.asarray(owner), 4)
+
+
+def _catalogue_params(entry, g, rng, source):
+    """One request's parameters, built from the entry's schema alone: the
+    batchable parameter takes ``source``, channel planes are random, and
+    every other parameter keeps its default.  A dense plane's rows are the
+    width of the program's vertex plane (its ``[F_in, F_out]`` weight)."""
+    params = {}
+    for spec in entry.params:
+        if spec.batchable:
+            params[spec.name] = source
+        elif spec.role == "channel":
+            rows = {"vertex": g.n_vertices, "edge": g.e_pad}.get(spec.channel)
+            if rows is None:
+                rows = next(s.features for s in entry.channel_params
+                            if s.channel == "vertex")
+            params[spec.name] = rng.random((rows, spec.features)) \
+                .astype(np.float32)
+    return params
+
+
+@pytest.mark.parametrize("program", sorted(
+    e.name for e in DEFAULT_REGISTRY.entries() if e.oracle is not None))
+def test_every_registered_program_serves_its_oracle(catalogue_plan, program):
+    """Each registered program with an oracle, served through a
+    GraphServer from four tenants, equals its oracle at the entry's
+    tolerance: batchable programs on four sources in one micro-batch,
+    the rest on one shared set of parameters."""
+    g, plan = catalogue_plan
+    entry = DEFAULT_REGISTRY.get(program)
+    rng = np.random.default_rng(1)
+    shared = _catalogue_params(entry, g, rng, 0)
+    reqs = [G.QueryRequest(program, tenant=f"t{i}",
+                           params=_catalogue_params(entry, g, rng, s)
+                           if entry.batchable else shared)
+            for i, s in enumerate((0, 17, 60, 149))]
+    srv = G.GraphServer(E.Engine(plan), g, buckets=(1, 2, 4),
+                        cache_entries=0)
+    try:
+        out = srv.serve(reqs)
+    finally:
+        srv.close()
+    assert [r.request.id for r in out] == [r.id for r in reqs]
+    for r in out:
+        _check(r, g)
 
 
 def test_result_cache_shared_across_tenants():

@@ -105,46 +105,3 @@ def test_minplus_iterated_equals_bfs():
         dist = nd
     ref_d, _ = reference_sssp(g, 0)
     np.testing.assert_allclose(np.asarray(dist), np.asarray(ref_d))
-
-
-# ---------------------------------------------------------------------------
-# selective_scan (Mamba-1 recurrence)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("b,s,d,n,blk,chunk", [
-    (2, 64, 32, 8, 16, 16),
-    (1, 100, 48, 16, 32, 32),   # non-divisible S -> padding path
-    (2, 128, 128, 16, 128, 64),
-])
-def test_selective_scan_matches_ref(b, s, d, n, blk, chunk):
-    ks = jax.random.split(jax.random.key(5), 6)
-    x = jax.random.normal(ks[0], (b, s, d))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, d)))
-    bb = jax.random.normal(ks[2], (b, s, n)) * 0.5
-    cc = jax.random.normal(ks[3], (b, s, n)) * 0.5
-    a = jnp.exp(jax.random.normal(ks[4], (d, n)) * 0.3)
-    d_skip = jax.random.normal(ks[5], (d,))
-    got = ops.selective_scan(x, dt, bb, cc, a, d_skip,
-                             block_d=blk, chunk=chunk)
-    want = ref.selective_scan_ref(x, dt, bb, cc, a, d_skip)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-4, rtol=2e-4)
-
-
-def test_selective_scan_matches_ssm_module():
-    """Kernel == the model's chunked associative scan (train path)."""
-    from repro.models.ssm import _selective_scan_chunked
-    b, s, d, n = 2, 64, 32, 8
-    ks = jax.random.split(jax.random.key(6), 6)
-    x = jax.random.normal(ks[0], (b, s, d))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, d)))
-    bb = jax.random.normal(ks[2], (b, s, n)) * 0.5
-    cc = jax.random.normal(ks[3], (b, s, n)) * 0.5
-    a = jnp.exp(jax.random.normal(ks[4], (d, n)) * 0.3)
-    d_skip = jax.random.normal(ks[5], (d,))
-    got = ops.selective_scan(x, dt, bb, cc, a, d_skip, block_d=16, chunk=16)
-    want, _ = _selective_scan_chunked(
-        x, dt, bb, cc, a, d_skip,
-        jnp.zeros((b, d, n), jnp.float32), chunk=32)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-4, rtol=2e-4)

@@ -251,3 +251,46 @@ def test_vertex_departure_and_return():
     _check_oracles(sess)
     d2 = np.asarray(E.engine_sssp(sess.engine, 0).state)
     assert d2[40] == 1.0 and d2[41] == 1.0
+
+
+# ---------------------------------------------------------------------------
+# compaction policies
+# ---------------------------------------------------------------------------
+
+def _bursty_forced_recompiles(policy) -> int:
+    """Forced recompiles of one policy over a seeded bursty workload:
+    bursts of 8% of |E| random inserts, each followed by an idle gap, on
+    the 3% email-enron stand-in with k = 8.  Two warmup bursts give the
+    policy its telemetry; the count covers the eight bursts after them."""
+    g = graph.load_dataset("email-enron", scale=0.03, seed=0)
+    rng = np.random.default_rng(11)
+    # no re-auctions: the counts isolate compaction scheduling
+    sess = S.StreamSession(g, S.StreamConfig(k=8, chunk_size=64,
+                                             drift_threshold=10.0),
+                           key=0, policy=policy)
+    burst = max(64, int(0.08 * g.n_edges))
+
+    def burst_edges():
+        e = rng.integers(0, g.n_vertices, size=(burst, 2))
+        return e[e[:, 0] != e[:, 1]]
+
+    for _ in range(2):
+        sess.apply(inserts=burst_edges())
+        sess.idle_tick()
+    forced0 = sess.n_forced_recompiles
+    for _ in range(8):
+        sess.apply(inserts=burst_edges())
+        sess.idle_tick()
+    return sess.n_forced_recompiles - forced0
+
+
+def test_adaptive_policy_absorbs_every_burst_reactive_is_forced():
+    """On the same workload the adaptive policy compacts in the idle gaps
+    and is never forced mid-burst, while the reactive default is forced
+    at least once (so the workload does exercise the policies)."""
+    adaptive = S.AdaptiveCompactionPolicy()
+    try:
+        assert _bursty_forced_recompiles(adaptive) == 0
+    finally:
+        adaptive.close()
+    assert _bursty_forced_recompiles(S.ReactiveCompactionPolicy()) >= 1
